@@ -34,15 +34,17 @@ push is smaller than the backward it hides under.
 Emits ``name,us_per_call,derived`` CSV rows plus one ``RESULT{...}`` JSON
 line.  Runs in a subprocess so the rank count gets its own XLA device
 count.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import emit, result
+from benchmarks.common import cpu_child_env, emit, result
 
 _SCRIPT = r"""
 import os, sys, json, time
@@ -57,7 +59,6 @@ from repro.graph import partition_graph, synthetic_graph
 from repro.launch.mesh import make_gnn_mesh
 from repro.pipeline import MinibatchPipeline
 from repro.train.gnn_trainer import DistTrainer, build_dist_data, layer_dims
-from repro.utils import compat
 
 def timeit(fn, reps):
     fn()                                   # compile / warm
@@ -130,12 +131,10 @@ def split(t, e):
     return rt[None], re[None]
 
 shard = P("data")
-fused_sm = jax.jit(compat.shard_map(fused, mesh=mesh,
-                                    in_specs=(shard, shard),
-                                    out_specs=(shard, shard)))
-split_sm = jax.jit(compat.shard_map(split, mesh=mesh,
-                                    in_specs=(shard, shard),
-                                    out_specs=(shard, shard)))
+fused_sm = jax.jit(jax.shard_map(fused, mesh=mesh, in_specs=(shard, shard),
+                                 out_specs=(shard, shard), check_vma=False))
+split_sm = jax.jit(jax.shard_map(split, mesh=mesh, in_specs=(shard, shard),
+                                 out_specs=(shard, shard), check_vma=False))
 ft, fe = fused_sm(tags, embs)
 st_, se = split_sm(tags, embs)
 assert (np.asarray(ft) == np.asarray(st_)).all()
@@ -216,8 +215,7 @@ print("RESULT" + json.dumps({
 
 
 def _run(R, V, reps):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(R), str(V), str(reps)],
         capture_output=True, text=True, env=env, check=False)

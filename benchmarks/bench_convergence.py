@@ -3,16 +3,18 @@
 Single-rank training establishes the target accuracy; distributed training
 must reach within 1% of it (the paper's protocol: distributed takes more
 epochs but converges to parity).  Reports epochs-to-target for 1 vs 4 ranks.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
 from benchmarks import common
-from benchmarks.common import emit
+from benchmarks.common import cpu_child_env, emit
 
 _SCRIPT = r"""
 import os, sys, json
@@ -53,9 +55,7 @@ print("RESULT" + json.dumps({"accs": accs, "losses": losses}))
 
 
 def run(r, epochs=10, vertices=6000):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env = cpu_child_env()
     p = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(r), str(epochs), str(vertices)],
         env=env, capture_output=True, text=True, timeout=1800)

@@ -4,15 +4,17 @@ Reports measured per-epoch wall time for both modes at equal rank count,
 measured per-step communication payloads, and the modeled epoch-time ratio
 on the target cluster (sync comm blocks; AEP comm overlaps) — the paper's
 5.2x at 64 ranks comes from exactly this volume+overlap gap.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import emit
+from benchmarks.common import cpu_child_env, emit
 
 _SCRIPT = r"""
 import os, sys, json, time
@@ -49,9 +51,7 @@ print("RESULT" + json.dumps({"epoch_s": dt, "acc": acc, "comm": comm}))
 
 
 def run(r, mode, vertices=6000):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env = cpu_child_env()
     p = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(r), mode, str(vertices)],
         env=env, capture_output=True, text=True, timeout=1200)

@@ -28,15 +28,17 @@ scale, so the optimization can't silently regress to a no-op.
 Emits ``name,us_per_call,derived`` CSV rows plus one ``RESULT{...}`` JSON
 line.  Runs in subprocesses so each rank count gets its own XLA device
 count.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import emit, result
+from benchmarks.common import cpu_child_env, emit, result
 
 _SCRIPT = r"""
 import os, sys, json, time
@@ -159,8 +161,7 @@ print("RESULT" + json.dumps({
 
 
 def _run(R, V, Q, mode="base"):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(R), str(V), str(Q), mode],
         capture_output=True, text=True, env=env, check=False)

@@ -4,15 +4,17 @@ The paper reports 71/47/37% hit-rates at layers L0/L1/L2 (cs=1M, ls=2,
 nc=2000, d=1, 64 ranks).  We sweep (cache_size, life_span) at our scale and
 report per-layer hit rates; the qualitative structure to reproduce is
 (a) L0 > deeper layers and (b) hit-rate increases with cs and ls.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import emit
+from benchmarks.common import cpu_child_env, emit
 
 _SCRIPT = r"""
 import os, sys, json
@@ -46,9 +48,7 @@ print("RESULT" + json.dumps({"rates": rates, "occ": occ}))
 
 
 def run(cs, ls, vertices=6000):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env = cpu_child_env()
     p = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(cs), str(ls), str(vertices)],
         env=env, capture_output=True, text=True, timeout=1200)

@@ -23,11 +23,13 @@ Runs each point in a subprocess so every sweep sets its own device count
 before jax imports — and uses >= 2 ranks: a single-rank partition has no
 halo pushes, so its training HECs stay empty and the audit (correctly)
 reports no signal.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
@@ -84,9 +86,7 @@ SPANS = [1, 4, 16, -1]
 
 
 def run(ls, epochs, vertices, ranks):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env = common.cpu_child_env()
     p = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(ls), str(epochs),
          str(vertices), str(ranks)],

@@ -19,16 +19,18 @@ Three costs the resilience plane is allowed to charge, measured:
 
 Runs in subprocesses so each piece gets its own XLA device count.  Emits
 ``name,us_per_call,derived`` CSV rows plus one ``RESULT{...}`` line.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import tempfile
 
-from benchmarks.common import emit, result
+from benchmarks.common import cpu_child_env, emit, result
 
 _CKPT_SCRIPT = r"""
 import os, sys, json, time
@@ -144,8 +146,7 @@ print("RESULT" + json.dumps({
 
 
 def _run(script, *argv):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     out = subprocess.run(
         [sys.executable, "-c", script, *[str(a) for a in argv]],
         capture_output=True, text=True, env=env, check=False)

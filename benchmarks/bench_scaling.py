@@ -6,15 +6,17 @@ show real scaling (R host devices time-share a core).  We therefore report
 per-step communication payload, and (c) a modeled epoch time on the target
 cluster (per-rank compute scaled 1/R, AEP comm overlapped, ARed blocking)
 mirroring the paper's epoch-time decomposition MBC+FWD+BWD+ARed.
+
+Its child processes are pinned to the CPU backend (``JAX_PLATFORMS=cpu``,
+see ``benchmarks.common.cpu_child_env``).
 """
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 import json
 
-from benchmarks.common import emit
+from benchmarks.common import cpu_child_env, emit
 
 _SCRIPT = r"""
 import os, sys, json, time
@@ -51,9 +53,7 @@ print("RESULT" + json.dumps({"epoch_s": dt, "steps": steps,
 
 
 def run_rank(r, model, vertices=6000):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src"))
+    env = cpu_child_env()
     p = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(r), model, str(vertices)],
         env=env, capture_output=True, text=True, timeout=1200)

@@ -34,6 +34,21 @@ def registry() -> obs.MetricsRegistry:
     return _registry
 
 
+def cpu_child_env() -> dict:
+    """Environment for a suite's JAX child process: the repo's ``src`` on
+    ``PYTHONPATH`` and the CPU backend pinned (``JAX_PLATFORMS=cpu``).  The
+    runner has touched JAX before the child starts, so on a TPU host the
+    parent holds the chip and a child that reached for it would fail or
+    hang on the chip's lock.  Chip-side work stays in one process."""
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def set_out_dir(path: str):
     """Where ``write_artifacts``/``artifact_path`` place files."""
     global _out_dir

@@ -14,6 +14,8 @@ from repro.serve.scheduler import Request, serve_requests
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-4b", choices=list_archs())
     ap.add_argument("--requests", type=int, default=6)

@@ -25,6 +25,8 @@ RANKS = 4
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     g = synthetic_graph(num_vertices=8_000, avg_degree=10, num_classes=8,
                         feat_dim=32, seed=1)
     ps = partition_graph(g, RANKS, seed=0)

@@ -24,6 +24,8 @@ RANKS = 4
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the obs registry (incl. per-rank health "

@@ -33,6 +33,8 @@ R = 4
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     g = synthetic_graph(num_vertices=4000, avg_degree=8, num_classes=8,
                         feat_dim=32, seed=0)
     ps = partition_graph(g, R, seed=0)

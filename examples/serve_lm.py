@@ -16,6 +16,8 @@ from repro.train import lm_trainer
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-4b", choices=list_archs())
     ap.add_argument("--batch", type=int, default=2)

@@ -6,9 +6,9 @@ Every cross-rank embedding movement in the repo goes through this engine:
     ``nc`` solid embeddings per remote rank from the static push contract
     (``ExchangePlan.push_mask``, one boolean gather — no per-step
     ``searchsorted`` probes), gather the per-layer embeddings, and move
-    tags + payload in ONE fused ``all_to_all`` (tags are bitcast into the
-    payload's leading lane, so the legacy two-collective push becomes a
-    single collective).  The received push lands in the delay-``d``
+    tags + payload in ONE fused ``all_to_all`` (tags ride as exact float
+    halves in the payload's leading lanes, so the legacy two-collective
+    push becomes a single collective).  The received push lands in the delay-``d``
     in-flight queue (``repro.core.aep``) and is HECStore'd ``d`` steps
     later — the paper's bounded staleness, bit-exact.
 
@@ -74,6 +74,24 @@ from repro.cache import hec as hec_lib
 from repro.cache import hot_tier as hot_lib
 from repro.comm.plan import ExchangePlan, build_exchange_plan
 from repro.core import aep
+
+
+def _tags_to_f32(tags):
+    """int32 ``[..., n]`` -> float32 ``[..., 2n]``: high and low 16-bit
+    halves as exact float values.  A bitcast would turn small tags into
+    denormal floats and -1 into a NaN, and the TPU compiler may route the
+    payload through float arithmetic (it lowers the pack's concatenate to
+    a ``maximum`` of padded operands), which flushes denormals to zero
+    and rewrites NaN bits."""
+    return jnp.concatenate([(tags >> 16).astype(jnp.float32),
+                            (tags & 0xFFFF).astype(jnp.float32)], axis=-1)
+
+
+def _f32_to_tags(halves):
+    """Inverse of :func:`_tags_to_f32`."""
+    n = halves.shape[-1] // 2
+    hi = halves[..., :n].astype(jnp.int32)
+    return (hi << 16) | halves[..., n:].astype(jnp.int32)
 
 
 class HaloExchangeEngine:
@@ -205,10 +223,10 @@ class HaloExchangeEngine:
         return tags, embs
 
     def push(self, tags, embs, hot=None):
-        """ONE fused all_to_all: int32 tags ride bitcast in a flat prefix
-        of the payload (pure data movement — bits survive the collective).
-        The pack is two contiguous block copies per rank row, not an
-        interleaved per-slot lane, so fusing costs no strided traffic.
+        """ONE fused all_to_all: int32 tags ride as exact float32 halves
+        (``_tags_to_f32``) in a flat prefix of the payload.  The pack is
+        contiguous block copies per rank row, not an interleaved per-slot
+        lane, so fusing costs no strided traffic.
 
         ``hot=(hot_tags [L, hb], hot_embs [L, hb, dmax])`` appends the
         hot-tier broadcast segment — identical bytes to every destination
@@ -217,30 +235,26 @@ class HaloExchangeEngine:
         ``(rec_hot_tags [R, L, hb], rec_hot_embs [R, L, hb, dmax])``."""
         R, L, nc = tags.shape
         dmax = embs.shape[-1]
-        tag_block = jax.lax.bitcast_convert_type(
-            tags, jnp.float32).reshape(R, L * nc)
-        blocks = [tag_block, embs.reshape(R, L * nc * dmax)]
+        blocks = [_tags_to_f32(tags.reshape(R, L * nc)),
+                  embs.reshape(R, L * nc * dmax)]
         if hot is not None:
             hot_tags, hot_embs = hot
             hb = hot_tags.shape[-1]
-            ht = jax.lax.bitcast_convert_type(
-                hot_tags, jnp.float32).reshape(1, L * hb)
-            blocks.append(jnp.broadcast_to(ht, (R, L * hb)))
+            ht = _tags_to_f32(hot_tags.reshape(1, L * hb))
+            blocks.append(jnp.broadcast_to(ht, (R, 2 * L * hb)))
             blocks.append(jnp.broadcast_to(
                 hot_embs.reshape(1, L * hb * dmax), (R, L * hb * dmax)))
         buf = jnp.concatenate(blocks, axis=-1)
         rec = jax.lax.all_to_all(buf, self.axis, 0, 0)
-        o = L * nc
-        rec_tags = jax.lax.bitcast_convert_type(
-            rec[:, :o], jnp.int32).reshape(R, L, nc)
+        o = 2 * L * nc
+        rec_tags = _f32_to_tags(rec[:, :o]).reshape(R, L, nc)
         rec_embs = rec[:, o:o + L * nc * dmax].reshape(R, L, nc, dmax)
         if hot is None:
             return rec_tags, rec_embs
         o += L * nc * dmax
         hb = hot[0].shape[-1]
-        rec_hot_tags = jax.lax.bitcast_convert_type(
-            rec[:, o:o + L * hb], jnp.int32).reshape(R, L, hb)
-        rec_hot_embs = rec[:, o + L * hb:].reshape(R, L, hb, dmax)
+        rec_hot_tags = _f32_to_tags(rec[:, o:o + 2 * L * hb]).reshape(R, L, hb)
+        rec_hot_embs = rec[:, o + 2 * L * hb:].reshape(R, L, hb, dmax)
         return rec_tags, rec_embs, rec_hot_tags, rec_hot_embs
 
     def aep_push(self, data, mb, captured, vid_o_nodes, num_solid, inflight,

@@ -36,7 +36,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
 from repro.graph.partition import PartitionSet
@@ -207,25 +207,25 @@ class ExchangePlan:
                 "baseline_rows": base, "hot_rows": hot,
                 "reduction": 1.0 - hot / base if base else 0.0}
 
-    def device_tables(self) -> dict:
+    def device_tables(self, sharding=None) -> dict:
         """The ``[R, ...]``-stacked tables a shard_map step consumes
-        (merged into the trainer's / server's sharded data dict).
+        (merged into the trainer's / server's sharded data dict), placed
+        with ``sharding`` (default: the default device).
         ``db_halo`` itself stays host-side: the push membership it encodes
         travels as the (denser to probe) ``push_mask``.  With a hot set,
         the sorted hot-vid table (every rank's copy is identical) and the
         per-rank ownership mask ride along."""
         out = {
-            "push_mask": jnp.asarray(self.push_mask),
-            "solid_sorted_vids": jnp.asarray(self.solid_sorted_vids),
-            "solid_sorted_idx": jnp.asarray(self.solid_sorted_idx),
+            "push_mask": self.push_mask,
+            "solid_sorted_vids": self.solid_sorted_vids,
+            "solid_sorted_idx": self.solid_sorted_idx,
         }
         if self.hot_size:
             R = self.num_ranks
-            out["hot_vids"] = jnp.asarray(
-                np.broadcast_to(self.hot_vids, (R, self.hot_size)))
-            out["hot_mine"] = jnp.asarray(
-                self.hot_owner[None, :] == np.arange(R)[:, None])
-        return out
+            out["hot_vids"] = np.broadcast_to(self.hot_vids,
+                                              (R, self.hot_size))
+            out["hot_mine"] = self.hot_owner[None, :] == np.arange(R)[:, None]
+        return jax.device_put(out, sharding)
 
 
 def build_exchange_plan(ps: PartitionSet,

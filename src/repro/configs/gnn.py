@@ -69,7 +69,6 @@ class SamplerConfig:
     device_draw: bool = False       # on-device kernel draw (host np default)
     cv_boost: float = 4.0           # cv: weight boost for HEC-resident rows
     use_kernel: bool = True         # Pallas keys kernel (False = jnp ref)
-    interpret: bool = True          # Pallas interpret mode (False on TPU)
 
     def __post_init__(self):
         if self.policy not in ("uniform", "labor", "cv"):

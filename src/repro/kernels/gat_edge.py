@@ -20,51 +20,52 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 
-def _gat_kernel(eu_ref, ev_ref, z_ref, mask_ref, out_ref, *, heads: int):
-    eu = eu_ref[...].astype(jnp.float32)          # [bm, f, H]
-    ev = ev_ref[...].astype(jnp.float32)          # [bm, H]
-    z = z_ref[...].astype(jnp.float32)            # [bm, f, H*dh]
-    m = mask_ref[...] > 0                         # [bm, f]
-    scores = eu + ev[:, None, :]
+
+def _gat_kernel(s_ref, z_ref, mask_ref, out_ref, *, heads: int):
+    scores = s_ref[...].astype(jnp.float32)       # [bm, f, H]
+    m = mask_ref[...] > 0                         # [bm, f, H]
     scores = jnp.where(scores >= 0, scores, 0.2 * scores)   # LeakyReLU(0.2)
-    scores = jnp.where(m[..., None], scores, -1e30)
+    scores = jnp.where(m, scores, -1e30)
     smax = scores.max(axis=1, keepdims=True)
     p = jnp.exp(scores - smax)
-    p = jnp.where(m[..., None], p, 0.0)
+    p = jnp.where(m, p, 0.0)
     alpha = p / jnp.maximum(p.sum(axis=1, keepdims=True), 1e-20)  # [bm,f,H]
-    bm, f, HD = z.shape
-    dh = HD // heads
-    zv = z.reshape(bm, f, heads, dh)
-    out = (alpha[..., None] * zv).sum(axis=1)     # broadcast over dh, reduce f
-    out_ref[...] = out.reshape(bm, HD).astype(out_ref.dtype)
+    dh = z_ref.shape[-1] // heads
+    for h in range(heads):      # per head: broadcast over dh, reduce f
+        cols = slice(h * dh, (h + 1) * dh)
+        zh = z_ref[:, :, cols].astype(jnp.float32)            # [bm, f, dh]
+        out = (alpha[:, :, h:h + 1] * zh).sum(axis=1)         # [bm, dh]
+        out_ref[:, cols] = out.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "bm", "interpret"))
-def gat_edge(eu_nbr, ev, z_nbr, mask, *, heads: int, bm: int = 64,
-             interpret=True):
+@functools.partial(jax.jit, static_argnames=("heads", "bm"))
+def gat_edge(eu_nbr, ev, z_nbr, mask, *, heads: int, bm: int = 64):
     """eu_nbr [M,f,H]; ev [M,H]; z_nbr [M,f,H*dh]; mask [M,f] -> [M,H*dh]."""
     M, f, H = eu_nbr.shape
     HD = z_nbr.shape[-1]
+    # the edge scores and the head-broadcast mask are formed here: Mosaic
+    # cannot insert a unit dim into a vector inside the kernel
+    scores = eu_nbr.astype(jnp.float32) + ev.astype(jnp.float32)[:, None, :]
+    mask = jnp.broadcast_to(mask[..., None], (M, f, H)).astype(jnp.int32)
     bm = min(bm, M)
     pad = (-M) % bm
     if pad:
-        eu_nbr = jnp.pad(eu_nbr, ((0, pad), (0, 0), (0, 0)))
-        ev = jnp.pad(ev, ((0, pad), (0, 0)))
+        scores = jnp.pad(scores, ((0, pad), (0, 0), (0, 0)))
         z_nbr = jnp.pad(z_nbr, ((0, pad), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
+        mask = jnp.pad(mask, ((0, pad), (0, 0), (0, 0)))
     Mp = M + pad
     out = pl.pallas_call(
         functools.partial(_gat_kernel, heads=heads),
         grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, f, H), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bm, H), lambda i: (i, 0)),
             pl.BlockSpec((bm, f, HD), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bm, f), lambda i: (i, 0)),
+            pl.BlockSpec((bm, f, H), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, HD), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, HD), jnp.float32),
-        interpret=interpret,
-    )(eu_nbr, ev, z_nbr, mask.astype(jnp.int32))
+        interpret=interpret_mode(),
+    )(scores, z_nbr, mask)
     return out[:M]

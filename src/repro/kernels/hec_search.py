@@ -26,33 +26,48 @@ from jax.experimental.pallas import tpu as pltpu
 # re-exports the same function object so kernel and cache can never drift
 # (parity pinned in tests/test_comm.py).
 from repro.cache.hec import set_index
+from repro.kernels import interpret_mode
+
+
+def _probe(vid, tags_ref, hit_ref, way_ref):
+    row = tags_ref[...]                       # [1, ways]
+    match = row == vid
+    any_hit = jnp.any(match) & (vid >= 0)
+    # first matching way (argmax of the match row; 0 on a miss)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    first = jnp.min(jnp.where(match, lane, row.shape[1]))
+    hit_ref[...] = jnp.full(hit_ref.shape, any_hit, jnp.int32)
+    way_ref[...] = jnp.full(way_ref.shape, jnp.where(any_hit, first, 0),
+                            jnp.int32)
 
 
 def _search_kernel(sets_ref, vids_ref, tags_ref, hit_ref, way_ref):
-    i = pl.program_id(0)
-    vid = vids_ref[i]
-    row = tags_ref[...]                       # [1, ways]
-    match = row[0, :] == vid
-    any_hit = jnp.any(match) & (vid >= 0)
-    hit_ref[...] = any_hit.reshape(1, 1)
-    way_ref[...] = jnp.argmax(match).astype(jnp.int32).reshape(1, 1)
+    _probe(vids_ref[pl.program_id(0)], tags_ref, hit_ref, way_ref)
 
 
 def _search_batched_kernel(sets_ref, vids_ref, tags_ref, hit_ref, way_ref,
                            *, n):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    vid = vids_ref[b * n + i]
-    row = tags_ref[...]                       # [1, ways]
-    match = row[0, :] == vid
-    any_hit = jnp.any(match) & (vid >= 0)
-    hit_ref[...] = any_hit.reshape(1, 1)
-    way_ref[...] = jnp.argmax(match).astype(jnp.int32).reshape(1, 1)
+    _probe(vids_ref[pl.program_id(0) * n + pl.program_id(1)], tags_ref,
+           hit_ref, way_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hec_search_batched(tags: jnp.ndarray, vids: jnp.ndarray, *,
-                       interpret=True):
+# One set row and one result per grid step travel as [1, ways] / [1, 1]
+# tiles of [nsets, 1, ways] / [n, 1, 1] arrays: a block's last two dims
+# then equal the array's, which Mosaic accepts.
+def _set_row(ways, index_map):
+    return pl.BlockSpec((None, 1, ways), index_map)
+
+
+def _result(index_map):
+    return pl.BlockSpec((None, 1, 1), index_map)
+
+
+def _results(n):
+    return [jax.ShapeDtypeStruct((n, 1, 1), jnp.int32)] * 2
+
+
+@jax.jit
+def hec_search_batched(tags: jnp.ndarray, vids: jnp.ndarray):
     """Probe N rounds' vids against one tag array in a single grid.
 
     tags [nsets, ways] int32; vids [B, n] int32 (B = fused exchange
@@ -68,27 +83,21 @@ def hec_search_batched(tags: jnp.ndarray, vids: jnp.ndarray, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bsz, n),
-        in_specs=[
-            pl.BlockSpec((1, ways), lambda b, i, s, v: (s[b * n + i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, s, v: (b * n + i, 0)),
-            pl.BlockSpec((1, 1), lambda b, i, s, v: (b * n + i, 0)),
-        ],
+        in_specs=[_set_row(ways, lambda b, i, s, v: (s[b * n + i], 0, 0))],
+        out_specs=[_result(lambda b, i, s, v: (b * n + i, 0, 0))] * 2,
     )
     hit, way = pl.pallas_call(
         functools.partial(_search_batched_kernel, n=n),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bsz * n, 1), jnp.bool_),
-                   jax.ShapeDtypeStruct((bsz * n, 1), jnp.int32)],
-        interpret=interpret,
-    )(sets, flat, tags)
-    return (hit[:, 0].reshape(bsz, n), sets.reshape(bsz, n),
-            way[:, 0].reshape(bsz, n))
+        out_shape=_results(bsz * n),
+        interpret=interpret_mode(),
+    )(sets, flat, tags.reshape(nsets, 1, ways))
+    return (hit.reshape(bsz, n) > 0, sets.reshape(bsz, n),
+            way.reshape(bsz, n))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hec_probe(state, vids: jnp.ndarray, *, interpret=True):
+@jax.jit
+def hec_probe(state, vids: jnp.ndarray):
     """Batched HECSearch + HECLoad: vids [B, n] -> (hit [B, n], emb [B, n, d]).
 
     Row-for-row bit-identical to ``hec.hec_lookup(state, vids[b])``: same
@@ -96,14 +105,13 @@ def hec_probe(state, vids: jnp.ndarray, *, interpret=True):
     zeroed miss rows — pinned in tests/test_kernels.py and consumed by
     ``HaloExchangeEngine.cache_fetch(rounds=N)``.
     """
-    hit, sets, way = hec_search_batched(state.tags, vids, interpret=interpret)
+    hit, sets, way = hec_search_batched(state.tags, vids)
     emb = jax.lax.stop_gradient(state.values[sets, way])
     return hit, jnp.where(hit[..., None], emb, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def hec_search_kernel(tags: jnp.ndarray, vids: jnp.ndarray, *,
-                      interpret=True):
+@jax.jit
+def hec_search_kernel(tags: jnp.ndarray, vids: jnp.ndarray):
     """tags [nsets, ways] int32; vids [n] int32 -> (hit [n], set [n], way [n])."""
     nsets, ways = tags.shape
     n = vids.shape[0]
@@ -111,19 +119,13 @@ def hec_search_kernel(tags: jnp.ndarray, vids: jnp.ndarray, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, ways), lambda i, s, v: (s[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, s, v: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, s, v: (i, 0)),
-        ],
+        in_specs=[_set_row(ways, lambda i, s, v: (s[i], 0, 0))],
+        out_specs=[_result(lambda i, s, v: (i, 0, 0))] * 2,
     )
     hit, way = pl.pallas_call(
         _search_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.bool_),
-                   jax.ShapeDtypeStruct((n, 1), jnp.int32)],
-        interpret=interpret,
-    )(sets, vids.astype(jnp.int32), tags)
-    return hit[:, 0], sets, way[:, 0]
+        out_shape=_results(n),
+        interpret=interpret_mode(),
+    )(sets, vids.astype(jnp.int32), tags.reshape(nsets, 1, ways))
+    return hit.reshape(n) > 0, sets, way.reshape(n)

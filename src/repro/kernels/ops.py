@@ -1,8 +1,5 @@
-"""Jit'd public wrappers around the Pallas kernels.
-
-``interpret=True`` executes the kernel bodies in Python on CPU (how this
-container validates them); on a real TPU pass ``interpret=False``.
-"""
+"""Jit'd public wrappers around the Pallas kernels (the backend picks
+interpret or compiled mode, see ``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
 import jax
@@ -22,7 +19,7 @@ __all__ = ["fused_update", "sage_agg", "gat_edge", "gat_edge_aggregate",
            "draw_neighbors_device"]
 
 
-def gat_edge_aggregate(z, e_u, e_v, nbr_idx, src_valid, *, interpret=True):
+def gat_edge_aggregate(z, e_u, e_v, nbr_idx, src_valid):
     """Model-facing wrapper: gathers neighbor tensors, runs the kernel.
 
     z [N_src, H, dh]; e_u [N_src, H]; e_v [N_src, H] (dst rows are the
@@ -34,6 +31,5 @@ def gat_edge_aggregate(z, e_u, e_v, nbr_idx, src_valid, *, interpret=True):
     mask = (nbr_idx >= 0) & src_valid[idx]
     eu_nbr = e_u[idx]                          # [M, f, H]
     z_nbr = z[idx].reshape(n_dst, f, H * dh)
-    out = gat_edge(eu_nbr, e_v[:n_dst], z_nbr, mask, heads=H,
-                   interpret=interpret)
+    out = gat_edge(eu_nbr, e_v[:n_dst], z_nbr, mask, heads=H)
     return out.reshape(n_dst, H, dh)

@@ -9,7 +9,7 @@ runs entirely on-device:
      candidate matrix (W = max degree), -1 past the row's degree,
   2. a Pallas kernel assigns every candidate a float32 key via the
      repo-wide u32 mix hash (``ref.sample_keys_ref`` is the jnp oracle —
-     bit-identical in interpret mode), policy-dependent:
+     bit-identical), policy-dependent:
        uniform  hash(row, slot)       iid neighbor sampling
        labor    hash(vid)             LABOR-style shared vertex keys
        cv       hash(vid)/weight      control-variate boost for vertices
@@ -32,6 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 # np scalars (not jnp) so the kernel body doesn't capture traced consts
 _MIX1 = np.uint32(0x85EBCA6B)
@@ -45,9 +48,10 @@ def _keys_kernel(nbr_ref, w_ref, seed_ref, out_ref, *, policy: str,
     i = pl.program_id(0)
     nbr = nbr_ref[...]                              # [bn, W] int32
     if policy == "uniform":
-        a = ((i * bn).astype(jnp.uint32)
-             + jax.lax.broadcasted_iota(jnp.uint32, (bn, width), 0))
-        b = jax.lax.broadcasted_iota(jnp.uint32, (bn, width), 1)
+        a = (i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, width), 0)
+             ).astype(jnp.uint32)
+        b = jax.lax.broadcasted_iota(jnp.int32, (bn, width), 1
+                                     ).astype(jnp.uint32)
     else:
         a = jnp.maximum(nbr, 0).astype(jnp.uint32)
         b = jnp.zeros_like(a)
@@ -55,15 +59,17 @@ def _keys_kernel(nbr_ref, w_ref, seed_ref, out_ref, *, policy: str,
     h = h ^ (h >> np.uint32(15))
     h = h * _MIX1
     h = h ^ (h >> np.uint32(13))
-    keys = (h >> np.uint32(8)).astype(jnp.float32) / np.float32(1 << 24)
+    # the top 24 bits fit int32 exactly; Mosaic has no u32 -> f32 cast
+    keys = (h >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+        / np.float32(1 << 24)
     if policy == "cv":
         keys = keys / jnp.maximum(w_ref[...], 1e-6)
     out_ref[...] = jnp.where(nbr >= 0, keys, jnp.inf)
 
 
-@functools.partial(jax.jit, static_argnames=("policy", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("policy", "bn"))
 def sample_keys_kernel(seed, nbr_vid, weights=None, *, policy="uniform",
-                       bn=1024, interpret=True):
+                       bn=1024):
     """Selection keys [n, W] float32 (+inf on -1 slots); f smallest win.
 
     Bit-matches ``kernels.ref.sample_keys_ref`` (pinned in tests).
@@ -86,21 +92,20 @@ def sample_keys_kernel(seed, nbr_vid, weights=None, *, policy="uniform",
         in_specs=[
             pl.BlockSpec((bn, width), lambda i: (i, 0)),
             pl.BlockSpec((bn, width), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bn, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, width), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(nbr_vid, weights.astype(jnp.float32), seed_arr)
     return out[:n]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "f", "num_solid", "width", "policy", "use_kernel", "interpret"))
+    "f", "num_solid", "width", "policy", "use_kernel"))
 def draw_neighbors_device(indptr, indices, wtab, cur, seed, allow, *,
                           f: int, num_solid: int, width: int,
-                          policy: str = "uniform", use_kernel: bool = True,
-                          interpret: bool = True):
+                          policy: str = "uniform", use_kernel: bool = True):
     """Device analogue of the host ``_draw_neighbors``: [n] -> [n, f].
 
     indptr [S+1], indices [E] — the partition's solid CSR (int32 on
@@ -132,8 +137,7 @@ def draw_neighbors_device(indptr, indices, wtab, cur, seed, allow, *,
         col = jnp.arange(f, dtype=jnp.int32)
     w = wtab[jnp.maximum(nbr, 0)] if policy == "cv" else None
     if use_kernel:
-        keys = sample_keys_kernel(seed, nbr, w, policy=policy,
-                                  interpret=interpret)
+        keys = sample_keys_kernel(seed, nbr, w, policy=policy)
     else:
         from repro.kernels import ref
         keys = ref.sample_keys_ref(seed, nbr, w, policy=policy)

@@ -10,13 +10,12 @@ the dst rows are read straight from the ``h_src`` prefix inside the
 kernel (the serve blocks' dst-prefix invariant).
 
 Memory spaces: every operand is passed as a whole-array ``ANY``-space
-ref rather than through gridded ``BlockSpec`` windows.  In interpret
-mode a gridded spec materializes a copy of each block per grid step
-(``lax.dynamic_slice`` in the grid loop), which for this kernel costs
-more than the layer math itself; whole-array refs make the fused call
-match — and on the serve step beat — the composed jnp path.  An on-TPU
-deployment would re-block the dst rows over a grid exactly like
-``update_fused`` and DMA ``h_src`` tiles on demand.
+ref rather than through gridded ``BlockSpec`` windows, and read with
+``[...]``.  Only the Pallas interpreter accepts that: Mosaic loads from
+VMEM and SMEM alone, so on a TPU the kernel needs a rewrite that
+re-blocks the dst rows over a grid like ``update_fused`` and DMAs
+``h_src`` tiles on demand.  Until then ``require_interpreter`` refuses
+it on every backend but the CPU.
 
 Parity: the in-kernel math is ``kernels.ref.serve_layer_ref`` op-for-op
 — bit-exact, pinned in tests/test_kernels.py — and both online
@@ -30,7 +29,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
+
+
+def require_interpreter():
+    """Raise unless the backend runs Pallas kernels in the interpreter."""
+    if not interpret_mode():
+        raise NotImplementedError(
+            f"fused_kernel=True is not supported on the "
+            f"{jax.default_backend()!r} backend: the fused serve layer "
+            f"loads whole ANY-space refs, which the Mosaic compiler "
+            f"refuses; serve with fused_kernel=False")
 
 
 def _serve_kernel(nbr_ref, h_ref, valid_ref, wn_ref, ws_ref, b_ref,
@@ -55,9 +65,8 @@ def _serve_kernel(nbr_ref, h_ref, valid_ref, wn_ref, ws_ref, b_ref,
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("relu", "interpret"))
-def fused_serve_layer(h_src, nbr_idx, src_valid, wn, ws, b, *, relu=True,
-                      interpret=True):
+@functools.partial(jax.jit, static_argnames=("relu",))
+def fused_serve_layer(h_src, nbr_idx, src_valid, wn, ws, b, *, relu=True):
     """One serve layer in one pallas_call.
 
     h_src [N, D] source activations; nbr_idx [M, f] (-1 pad);
@@ -67,23 +76,23 @@ def fused_serve_layer(h_src, nbr_idx, src_valid, wn, ws, b, *, relu=True,
     invariant — same contract as ``graphsage.forward``), read in-kernel
     rather than passed as an operand.
     """
+    require_interpreter()
     M, _ = nbr_idx.shape
     K = wn.shape[1]
-    spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_serve_kernel, relu=relu),
         grid=(1,),
         in_specs=[spec] * 6,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((M, K), jnp.float32),
-        interpret=interpret,
+        interpret=True,
     )(nbr_idx.astype(jnp.int32), h_src, src_valid.astype(jnp.bool_),
       wn, ws, b)
 
 
 def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
-            seed=None, halo_hook=None, use_kernel: bool = True,
-            interpret: bool = True):
+            seed=None, halo_hook=None, use_kernel: bool = True):
     """Drop-in for ``graphsage.forward`` on the serve path (dropout off).
 
     Same signature and hook contract: halo_hook(k, h, valid) runs on the
@@ -103,7 +112,7 @@ def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
         p = params["layers"][k]
         last = k == L - 1
         h_new = fused_serve_layer(h, nbr, valid, p["wn"], p["ws"], p["b"],
-                                  relu=not last, interpret=interpret)
+                                  relu=not last)
         valid = valid[:n_dst]
         if halo_hook is not None and not last:
             h_new, valid = halo_hook(k + 1, h_new, valid)

@@ -23,6 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 _MIX1 = np.uint32(0x85EBCA6B)
 _MIX2 = np.uint32(0xC2B2AE35)
@@ -36,28 +39,29 @@ def _update_kernel(agg_ref, self_ref, wn_ref, ws_ref, b_ref, seed_ref,
                   preferred_element_type=jnp.float32)
     acc += jnp.dot(self_ref[...], ws_ref[...],
                    preferred_element_type=jnp.float32)
-    acc += b_ref[...][None, :].astype(jnp.float32)
+    acc += b_ref[...].astype(jnp.float32)             # [1, bk] row
     if relu:
         acc = jnp.maximum(acc, 0.0)
     if dropout > 0.0:
-        rows = ((i * bn).astype(jnp.uint32)
-                + jax.lax.broadcasted_iota(jnp.uint32, (bn, bk), 0))
-        cols = ((j * bk).astype(jnp.uint32)
-                + jax.lax.broadcasted_iota(jnp.uint32, (bn, bk), 1))
+        rows = (i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 0)
+                ).astype(jnp.uint32)
+        cols = (j * bk + jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
+                ).astype(jnp.uint32)
         h = (rows * _MIX1) ^ (cols * _MIX2) ^ seed_ref[0]
         h = h ^ (h >> np.uint32(15))
         h = h * _MIX1
         h = h ^ (h >> np.uint32(13))
-        u = (h >> np.uint32(8)).astype(jnp.float32) / np.float32(1 << 24)
+        # the top 24 bits fit int32 exactly; Mosaic has no u32 -> f32 cast
+        u = (h >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+            / np.float32(1 << 24)
         acc = jnp.where(u >= jnp.float32(dropout),
                         acc / jnp.float32(1.0 - dropout), 0.0)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("relu", "dropout", "bn", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("relu", "dropout", "bn", "bk"))
 def fused_update(agg, self_h, wn, ws, b, *, relu=True, dropout=0.0,
-                 seed=jnp.uint32(0), bn=256, bk=128, interpret=True):
+                 seed=jnp.uint32(0), bn=256, bk=128):
     """agg, self_h: [N, C]; wn, ws: [C, K]; b: [K] -> [N, K] float32."""
     N, C = agg.shape
     K = wn.shape[1]
@@ -84,11 +88,11 @@ def fused_update(agg, self_h, wn, ws, b, *, relu=True, dropout=0.0,
             pl.BlockSpec((bn, C), lambda i, j: (i, 0)),
             pl.BlockSpec((C, bk), lambda i, j: (0, j)),
             pl.BlockSpec((C, bk), lambda i, j: (0, j)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bn, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Np, Kp), jnp.float32),
-        interpret=interpret,
-    )(agg, self_h, wn, ws, b, seed_arr)
+        interpret=interpret_mode(),
+    )(agg, self_h, wn, ws, b.reshape(1, Kp), seed_arr)
     return out[:N, :K]

@@ -162,6 +162,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, verbose=True) -> dict:
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

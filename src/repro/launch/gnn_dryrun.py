@@ -20,6 +20,8 @@ import numpy as np
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--ranks", type=int, default=64)
     ap.add_argument("--model", default="graphsage",

@@ -24,6 +24,8 @@ import numpy as np
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--vertices", type=int, default=20_000)
     ap.add_argument("--model", default="graphsage",

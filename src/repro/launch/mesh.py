@@ -25,5 +25,11 @@ def make_mesh(shape, axes):
 
 
 def make_gnn_mesh(num_ranks: int):
-    """1-D mesh for the paper's rank-per-partition GNN trainer."""
-    return jax.make_mesh((num_ranks,), ("data",))
+    """1-D mesh for the paper's rank-per-partition GNN trainer.
+
+    The axis is ``Auto``: the trainer and the serve schedulers place
+    ``[R, ...]`` arrays with ``NamedSharding`` and run host-side
+    ``jit(vmap(...))`` lookups over them, which jax's default ``Explicit``
+    axes refuse ("inconsistent axis specs")."""
+    return jax.make_mesh((num_ranks,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

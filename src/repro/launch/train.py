@@ -3,8 +3,8 @@
 GNN (the paper's workload):
   python -m repro.launch.train gnn --model graphsage --ranks 4 \
       --vertices 20000 --epochs 5 --mode aep
-  (add XLA_FLAGS=--xla_force_host_platform_device_count=<ranks> when the
-   host has fewer real devices than ranks)
+  (one device per rank; on a CPU-only host, JAX_PLATFORMS=cpu
+   XLA_FLAGS=--xla_force_host_platform_device_count=<ranks> provides them)
 
 LM (assigned architectures, reduced configs on CPU):
   python -m repro.launch.train lm --arch minitron-4b --steps 20 \
@@ -13,7 +13,6 @@ LM (assigned architectures, reduced configs on CPU):
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -36,21 +35,38 @@ def _prom_writer(args, obs):
     return obs.PromFileWriter(args.prom_out, min_interval_s=1.0)
 
 
-def run_gnn(args):
+def require_devices(n: int) -> None:
+    """Exit unless JAX sees at least ``n`` devices (one per rank)."""
     import jax
-    import numpy as np
-    from repro.configs.gnn import (GAT_PAPERS100M, GRAPHSAGE_PAPERS100M,
-                                   HECConfig, small_gnn_config)
-    from repro.graph import partition_graph, synthetic_graph
+    if jax.device_count() < n:
+        raise SystemExit(f"need {n} devices, one per rank; JAX found "
+                         f"{jax.device_count()}: {jax.devices()}")
+
+
+def setup_gnn(ps, cfg, *, seed: int = 0, **trainer_kw):
+    """Per-rank tables, mesh, trainer and initial state for a partitioned
+    graph: the setup ``run_gnn`` and ``chip_smoke.py`` share.  Returns
+    ``(dist_data, trainer, state)``; ``trainer_kw`` go to ``DistTrainer``."""
+    import jax
     from repro.launch.mesh import make_gnn_mesh
-    from repro.train import checkpoint
     from repro.train.gnn_trainer import DistTrainer, build_dist_data
 
+    R = ps.num_parts
+    require_devices(R)
+    mesh = make_gnn_mesh(R)
+    dd = build_dist_data(ps, cfg, mesh)
+    tr = DistTrainer(cfg=cfg, mesh=mesh, num_ranks=R, **trainer_kw)
+    state = tr.init_state(jax.random.key(seed), dd)
+    return dd, tr, state
+
+
+def run_gnn(args):
+    from repro.configs.gnn import HECConfig, small_gnn_config
+    from repro.graph import partition_graph, synthetic_graph
+    from repro.train import checkpoint
+
     obs = _configure_obs(args)
-    if jax.device_count() < args.ranks:
-        raise SystemExit(
-            f"need {args.ranks} devices, have {jax.device_count()}; set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={args.ranks}")
+    require_devices(args.ranks)
 
     g = synthetic_graph(num_vertices=args.vertices, avg_degree=args.degree,
                         num_classes=args.classes, feat_dim=args.feat_dim,
@@ -68,8 +84,6 @@ def run_gnn(args):
         hec=HECConfig(cache_size=args.hec_size, ways=8,
                       life_span=args.hec_ls, push_limit=args.hec_nc,
                       delay=args.hec_delay))
-    dd = build_dist_data(ps, cfg)
-    mesh = make_gnn_mesh(args.ranks)
     # cluster health plane: per-rank epoch series + skew/drift detectors
     # over the partitioning's expected halo distribution; train_epochs
     # dumps FLIGHT_*.json if a detector fires or the step loop dies
@@ -100,10 +114,8 @@ def run_gnn(args):
             schedule=schedule, flight_dir=args.flight_dir))
         if schedule is not None:
             print(f"fault schedule: {len(schedule.specs)} scheduled faults")
-    tr = DistTrainer(cfg=cfg, mesh=mesh, num_ranks=args.ranks,
-                     mode=args.mode, health=health, quality=quality,
-                     resilience=rz)
-    state = tr.init_state(jax.random.key(args.seed))
+    dd, tr, state = setup_gnn(ps, cfg, seed=args.seed, mode=args.mode,
+                              health=health, quality=quality, resilience=rz)
     start_epoch = 0
     if args.resume:
         if rz is None or rz.ckpt is None:
@@ -198,6 +210,8 @@ def run_lm(args):
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 

@@ -37,8 +37,7 @@ def init_params(key, feat_dim: int, hidden: int, num_classes: int,
     return {"layers": layers}
 
 
-def gat_layer(p, h_src, nbr_idx, valid, *, use_kernel=False,
-              interpret=True):
+def gat_layer(p, h_src, nbr_idx, valid, *, use_kernel=False):
     """h_src [N_src, din] -> h_dst [N_dst, H*dh] (pre-dropout)."""
     z = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h_src, p["w"]) + p["b"])
     e_u = (z * p["a_u"]).sum(-1)                       # [N_src, H]
@@ -46,8 +45,7 @@ def gat_layer(p, h_src, nbr_idx, valid, *, use_kernel=False,
     n_dst = nbr_idx.shape[0]
     if use_kernel:
         from repro.kernels import ops as kops
-        h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid,
-                                    interpret=interpret)
+        h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid)
     else:
         idx = jnp.maximum(nbr_idx, 0)
         mask = (nbr_idx >= 0) & valid[idx]             # [N_dst, f]

@@ -36,12 +36,11 @@ def init_params(key, feat_dim: int, hidden: int, num_classes: int,
 
 
 def update(p, agg, self_h, *, relu: bool, dropout: float, seed,
-           use_kernel: bool = False, interpret: bool = True):
+           use_kernel: bool = False):
     if use_kernel:
         from repro.kernels import ops as kops
         return kops.fused_update(agg, self_h, p["wn"], p["ws"], p["b"],
-                                 relu=relu, dropout=dropout, seed=seed,
-                                 interpret=interpret)
+                                 relu=relu, dropout=dropout, seed=seed)
     out = agg @ p["wn"] + self_h @ p["ws"] + p["b"]
     if relu:
         out = jax.nn.relu(out)

@@ -68,8 +68,7 @@ class SamplingPlan:
             self._dev_samplers = [
                 DeviceSampler(p, base_seed=self.base_seed, rank=r,
                               policy=s.policy, cv_boost=s.cv_boost,
-                              use_kernel=s.use_kernel,
-                              interpret=s.interpret)
+                              use_kernel=s.use_kernel)
                 for r, p in enumerate(self.ps.parts)]
         return self._dev_samplers
 

@@ -197,7 +197,7 @@ class DeviceSampler:
 
     def __init__(self, part: Partition, base_seed: int = 0, rank: int = 0,
                  policy: str = "uniform", cv_boost: float = 4.0,
-                 use_kernel: bool = True, interpret: bool = True):
+                 use_kernel: bool = True):
         import jax.numpy as jnp     # lazy: module stays importable w/o jax
         self.part = part
         self.base_seed = int(base_seed)
@@ -205,7 +205,6 @@ class DeviceSampler:
         self.policy = policy
         self.cv_boost = float(cv_boost)
         self.use_kernel = bool(use_kernel)
-        self.interpret = bool(interpret)
         self.num_solid = part.num_solid
         deg = part.indptr[1:] - part.indptr[:-1]
         self.width = max(int(deg.max()) if part.num_solid else 0, 1)
@@ -244,7 +243,7 @@ class DeviceSampler:
                 self._seed(epoch, step, layer), allow_j,
                 f=int(f), num_solid=int(self.num_solid),
                 width=self.width, policy=self.policy,
-                use_kernel=self.use_kernel, interpret=self.interpret)
+                use_kernel=self.use_kernel)
         return np.asarray(out).astype(np.int64)
 
 

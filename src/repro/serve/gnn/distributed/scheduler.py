@@ -90,7 +90,6 @@ from repro.serve.gnn.distributed.sharded_cache import ShardedServingCache
 from repro.serve.gnn.embedding_cache import ServeCacheConfig
 from repro.serve.gnn.offline import serve_layer_dims
 from repro.serve.gnn.scheduler import GNNRequest, ServeFrontend
-from repro.utils import compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,6 +266,7 @@ class DistGNNServeScheduler(ServeFrontend):
         hot_layers = L if with_hot else 0
         if self._fused:
             from repro.kernels import serve_fused
+            serve_fused.require_interpreter()
             fwd = serve_fused.forward
         else:
             fwd = sage_lib.forward if cfg.model == "graphsage" \
@@ -416,10 +416,10 @@ class DistGNNServeScheduler(ServeFrontend):
                 return body(params, states, tstates, data, mb, None)
             in_specs = (repl, [shard] * L, [shard] * hot_layers, shard,
                         shard)
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             stepf, mesh=self.mesh, in_specs=in_specs,
             out_specs=(shard, shard, [shard] * L, [shard] * hot_layers,
-                       shard))
+                       shard), check_vma=False)
         return jax.jit(smapped)
 
     # -- public API ----------------------------------------------------------

@@ -196,6 +196,7 @@ class GNNServeScheduler(ServeFrontend):
         L = cfg.num_layers
         if self._fused:
             from repro.kernels import serve_fused
+            serve_fused.require_interpreter()
             fwd = serve_fused.forward
         else:
             fwd = sage_lib.forward if cfg.model == "graphsage" \

@@ -32,13 +32,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.cache import hec as hec_lib
@@ -54,32 +55,36 @@ from repro.resilience.inject import CODE_NAN_STEP
 from repro.models.gnn import gat as gat_lib
 from repro.models.gnn import graphsage as sage_lib
 from repro.train import optimizer as opt_lib
-from repro.utils import compat
 
 
 # ---------------------------------------------------------------------------
 # host-side data preparation
 # ---------------------------------------------------------------------------
-def build_dist_data(ps: PartitionSet, cfg: GNNConfig) -> dict:
+def rank_sharding(mesh) -> NamedSharding:
+    """Leading ``[R, ...]`` axis split over the mesh: one rank per device."""
+    return NamedSharding(mesh, P("data"))
+
+
+def build_dist_data(ps: PartitionSet, cfg: GNNConfig, mesh=None) -> dict:
     """Stacked per-rank device tables: features/labels/id maps plus the
     static exchange-plan tables (db_halo, push_mask, sorted owner tables,
     and — when ``cfg.hec.hot_size`` — the hot-set tables) the
     ``HaloExchangeEngine`` consumes — all computed once per partitioning,
-    never per step."""
+    never per step.  With a ``mesh`` each rank's rows go straight to its
+    own device."""
+    sharding = rank_sharding(mesh) if mesh is not None else None
     plan_tables = build_exchange_plan(
         ps, host_indices=False,
-        hot_size=cfg.hec.hot_size).device_tables()
-    feats = _pad_stack([p.features for p in ps.parts], 0.0)
-    labels = _pad_stack([p.labels.astype(np.int32) for p in ps.parts], 0)
-    num_solid = np.array([p.num_solid for p in ps.parts], np.int32)
-    vid_o = _pad_stack([p.vid_p_to_o().astype(np.int32) for p in ps.parts], -1)
-    return {
-        "features": jnp.asarray(feats),
-        "labels": jnp.asarray(labels),
-        "num_solid": jnp.asarray(num_solid),
-        "vid_o": jnp.asarray(vid_o),
-        **plan_tables,
+        hot_size=cfg.hec.hot_size).device_tables(sharding)
+    host = {
+        "features": _pad_stack([p.features for p in ps.parts], 0.0),
+        "labels": _pad_stack([p.labels.astype(np.int32) for p in ps.parts],
+                             0),
+        "num_solid": np.array([p.num_solid for p in ps.parts], np.int32),
+        "vid_o": _pad_stack([p.vid_p_to_o().astype(np.int32)
+                             for p in ps.parts], -1),
     }
+    return {**jax.device_put(host, sharding), **plan_tables}
 
 
 def sample_step(ps: PartitionSet, cfg: GNNConfig, seed_lists, rng) -> dict:
@@ -196,14 +201,22 @@ class DistTrainer:
     def init_state(self, key, dist_data=None):
         cfg = self.cfg
         R = self.num_ranks
+        # replicated exactly as the step returns them, so the step's
+        # first call compiles the same program as every later one
         params = init_model_params(key, cfg)
-        opt_state = opt_lib.adam_init(params)
+        params, opt_state = jax.device_put(
+            (params, opt_lib.adam_init(params)), NamedSharding(self.mesh, P()))
         dims = layer_dims(cfg)
-        hec = [
-            jax.vmap(lambda _: hec_lib.hec_init(
-                cfg.hec.cache_size, cfg.hec.ways, dims[l]))(jnp.arange(R))
-            for l in range(cfg.num_layers)
-        ]
+
+        def per_rank(make):
+            """[R, ...] stack of make()'s pytree, each rank's slice built
+            on its own device (no device ever holds another rank's)."""
+            return jax.jit(lambda: jax.vmap(lambda _: make())(jnp.arange(R)),
+                           out_shardings=rank_sharding(self.mesh))()
+
+        hec = [per_rank(functools.partial(hec_lib.hec_init, cfg.hec.cache_size,
+                                          cfg.hec.ways, dims[l]))
+               for l in range(cfg.num_layers)]
         # replicated hot-vertex tier: one [R, K, dim] replica stack per
         # layer, alive only when the plan derived a non-empty hot set (a
         # partitioning with no halos has no communication tail to cut)
@@ -240,9 +253,11 @@ class DistTrainer:
                         f"staleness window; unrefreshed replicas go "
                         f"stale and those hub halos degrade like HEC "
                         f"misses (dropped from aggregation)")
-                hot = [jax.vmap(lambda _: hot_lib.tier_init(K, dims[l]))(
-                    jnp.arange(R)) for l in range(cfg.num_layers)]
-        inflight = self.engine.inflight_init(max(dims))
+                hot = [per_rank(functools.partial(hot_lib.tier_init, K,
+                                                  dims[l]))
+                       for l in range(cfg.num_layers)]
+        inflight = jax.jit(lambda: self.engine.inflight_init(max(dims)),
+                           out_shardings=rank_sharding(self.mesh))()
         return {"params": params, "opt_state": opt_state, "hec": hec,
                 "hot": hot, "inflight": inflight,
                 "step": jnp.zeros((), jnp.int32)}
@@ -513,11 +528,12 @@ class DistTrainer:
             in_specs = (repl, repl, [shard] * cfg.num_layers,
                         [shard] * hot_layers, shard, shard, shard, repl)
 
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             stepf, mesh=self.mesh,
             in_specs=in_specs,
             out_specs=(repl, repl, [shard] * cfg.num_layers,
-                       [shard] * hot_layers, shard, shard, repl))
+                       [shard] * hot_layers, shard, shard, repl),
+            check_vma=False)
         return jax.jit(smapped,
                        donate_argnums=(1, 2, 3, 4) if donate else ())
 

@@ -447,13 +447,13 @@ def test_push_hot_segment_roundtrip():
     unpack are bit-exact for tags, payload, hot slot ids, and hot rows
     (single-device mesh, where the collective is the identity)."""
     from jax.sharding import PartitionSpec as P
-    from repro.utils import compat
     R, L, nc, hb, dmax = 1, 2, 3, 2, 5
     engine = HaloExchangeEngine(R, L, nc, hot_budget=hb)
     rng = np.random.default_rng(0)
-    tags = jnp.asarray(rng.integers(-1, 100, (R, R, L, nc)), jnp.int32)
+    tags = jnp.asarray(rng.integers(-1, 2**31 - 1, (R, R, L, nc)),
+                       jnp.int32)
     embs = jnp.asarray(rng.normal(size=(R, R, L, nc, dmax)), jnp.float32)
-    h_tags = jnp.asarray([[0, -1], [1, 0]], jnp.int32)          # [L, hb]
+    h_tags = jnp.asarray([[0, -1], [1, 2**31 - 1]], jnp.int32)  # [L, hb]
     h_embs = jnp.asarray(rng.normal(size=(L, hb, dmax)), jnp.float32)
 
     mesh = jax.make_mesh((1,), ("data",))
@@ -464,9 +464,8 @@ def test_push_hot_segment_roundtrip():
         return rt[None], re[None], rht[None], rhe[None]
 
     shard = P("data")
-    f = jax.jit(compat.shard_map(run, mesh=mesh,
-                                 in_specs=(shard, shard),
-                                 out_specs=(shard,) * 4))
+    f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(shard, shard),
+                              out_specs=(shard,) * 4, check_vma=False))
     rt, re, rht, rhe = f(tags, embs)
     np.testing.assert_array_equal(np.asarray(rt), np.asarray(tags))
     np.testing.assert_array_equal(np.asarray(re), np.asarray(embs))
@@ -498,3 +497,21 @@ def test_consume_push_feeds_tier():
     assert age0[0] == 0 and age0[1] == 0          # slots 0 (src 0), 1 (src 1)
     assert age0[2] > 2 and age0[3] > 2            # untouched slots stay stale
     assert np.asarray(hot[1].age)[2] == 0         # layer 1 slot from src 0
+
+
+def test_push_tags_travel_as_exact_normal_floats():
+    """Tags in the fused payload are float32 values a float op cannot
+    change: integers in [-2**15, 2**16), never denormals or NaNs (the TPU
+    compiler may lower the pack through a float ``maximum``, which flushes
+    denormals and rewrites NaN bits)."""
+    from repro.comm.engine import _f32_to_tags, _tags_to_f32
+    tags = jnp.asarray([-2**31, -1, 0, 1, 7, 2**16 - 1, 2**16, 123456789,
+                        2**31 - 1], jnp.int32)
+    halves = np.asarray(_tags_to_f32(tags))
+    assert np.all(np.isfinite(halves))
+    assert np.all(halves == np.round(halves))
+    assert np.all((halves == 0) | (np.abs(halves) >= 1.0))
+    assert halves.min() >= -2**15 and halves.max() < 2**16
+    np.testing.assert_array_equal(
+        np.asarray(_f32_to_tags(jnp.maximum(jnp.asarray(halves), -jnp.inf))),
+        np.asarray(tags))

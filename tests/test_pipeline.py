@@ -305,21 +305,23 @@ def test_device_draw_bitreproducible_any_worker_count(ps):
 def test_device_draw_uniform_pinned_trace():
     """Pinned reference trace: the uniform device draw for a fixed
     (graph, base_seed, epoch, step) must never drift — it is part of the
-    checkpoint-compatibility surface."""
+    checkpoint-compatibility surface.  Pinned under jax >= 0.5, whose
+    default ``jax_threefry_partitionable=True`` derives other bits from the
+    same fold_in chain than the 0.4 default did."""
     from repro.pipeline.vectorized_sampler import DeviceSampler
     g = synthetic_graph(num_vertices=300, avg_degree=5, num_classes=4,
                         feat_dim=8, seed=11)
     part = partition_graph(g, 1, seed=0).parts[0]
     dev = DeviceSampler(part, base_seed=13)
     out = dev.draw(2, 3, 0, np.arange(8, dtype=np.int64), 4)
-    want = np.array([[147, 117, 235,  81],
-                     [ 95, 218, 265, 241],
-                     [170, 174,  87, 183],
-                     [ 44,  30, 270, 272],
-                     [241, 111, 229, 247],
-                     [ 23,  14, 267, 290],
-                     [247,  97, 158, 289],
-                     [  9,  79,   1,  42]])
+    want = np.array([[147, 176, 243, 235],
+                     [225, 212,  95,  82],
+                     [130, 174,  87, 274],
+                     [ 96, 115, 270,  30],
+                     [247, 111, 289, 229],
+                     [ 23, 144, 290,  80],
+                     [289, 266,  35,  54],
+                     [ 79,   1,  64,  98]])
     np.testing.assert_array_equal(np.asarray(out), want)
 
 
