@@ -1,5 +1,5 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the Pallas kernel bodies on CPU)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (on the CPU
+backend the Pallas kernel bodies run in the interpreter)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
