@@ -245,7 +245,8 @@ class HaloExchangeEngine:
             blocks.append(jnp.broadcast_to(
                 hot_embs.reshape(1, L * hb * dmax), (R, L * hb * dmax)))
         buf = jnp.concatenate(blocks, axis=-1)
-        rec = jax.lax.all_to_all(buf, self.axis, 0, 0)
+        with jax.named_scope("aep_exchange"):
+            rec = jax.lax.all_to_all(buf, self.axis, 0, 0)
         o = 2 * L * nc
         rec_tags = _f32_to_tags(rec[:, :o]).reshape(R, L, nc)
         rec_embs = rec[:, o:o + L * nc * dmax].reshape(R, L, nc, dmax)
@@ -278,8 +279,9 @@ class HaloExchangeEngine:
         zero code computes identical bits to ``fault_code=None``."""
         from repro.resilience.inject import (CODE_CORRUPT_PUSH,
                                              CODE_DROP_PUSH)
-        tags, embs = self.select_push(data, mb, captured, vid_o_nodes,
-                                      num_solid, seed, dims, dmax, me)
+        with jax.named_scope("aep_pack"):
+            tags, embs = self.select_push(data, mb, captured, vid_o_nodes,
+                                          num_solid, seed, dims, dmax, me)
         if fault_code is not None:
             rowok = jnp.isfinite(embs).all(axis=-1)       # [R, L, nc]
             tags = jnp.where(rowok, tags, -1)
@@ -297,9 +299,10 @@ class HaloExchangeEngine:
                 * (4.0 + 4.0 * dims[l])
         stats = {"push_rows": rows, "push_bytes": nbytes}
         if self.hot_budget and "hot_tags" in inflight:
-            h_tags, h_embs = self.select_hot_push(
-                data, mb, captured, vid_o_nodes, num_solid, seed, dims,
-                dmax, me)
+            with jax.named_scope("aep_pack"):
+                h_tags, h_embs = self.select_hot_push(
+                    data, mb, captured, vid_o_nodes, num_solid, seed, dims,
+                    dmax, me)
             if fault_code is not None:
                 # NaN containment for the broadcast segment too (wire
                 # faults target only the pairwise payload)
@@ -332,21 +335,23 @@ class HaloExchangeEngine:
         dropped from aggregation exactly like an HEC miss (hot vids left
         the pairwise contract, so the HEC holds no copy): the same
         bounded-degradation semantics, same staleness bound."""
-        hec = [hec_lib.hec_tick(h, life_span) for h in hec]
-        for l in range(self.num_layers):
-            tl = inflight["tags"][0, :, l].reshape(-1)
-            el = inflight["embs"][0, :, l, :, :dims[l]].reshape(-1, dims[l])
-            hec[l] = hec_lib.hec_store(hec[l], tl, el)
-        if hot is None:
-            return hec
-        out_hot = []
-        for l in range(self.num_layers):
-            t = hot_lib.tier_tick(hot[l])
-            sl = inflight["hot_tags"][0, :, l].reshape(-1)
-            el = inflight["hot_embs"][0, :, l, :, :dims[l]].reshape(
-                -1, dims[l])
-            out_hot.append(hot_lib.tier_store(t, sl, el))
-        return hec, out_hot
+        with jax.named_scope("aep_consume"):
+            hec = [hec_lib.hec_tick(h, life_span) for h in hec]
+            for l in range(self.num_layers):
+                tl = inflight["tags"][0, :, l].reshape(-1)
+                el = inflight["embs"][0, :, l, :, :dims[l]].reshape(
+                    -1, dims[l])
+                hec[l] = hec_lib.hec_store(hec[l], tl, el)
+            if hot is None:
+                return hec
+            out_hot = []
+            for l in range(self.num_layers):
+                t = hot_lib.tier_tick(hot[l])
+                sl = inflight["hot_tags"][0, :, l].reshape(-1)
+                el = inflight["hot_embs"][0, :, l, :, :dims[l]].reshape(
+                    -1, dims[l])
+                out_hot.append(hot_lib.tier_store(t, sl, el))
+            return hec, out_hot
 
     # -- sync baseline fetch (device, inside shard_map) -------------------------
     def sync_fetch(self, data, vid0, is_halo0, h0):

@@ -37,25 +37,28 @@ def init_params(key, feat_dim: int, hidden: int, num_classes: int,
     return {"layers": layers}
 
 
-def gat_layer(p, h_src, nbr_idx, valid, *, use_kernel=False):
-    """h_src [N_src, din] -> h_dst [N_dst, H*dh] (pre-dropout)."""
-    z = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h_src, p["w"]) + p["b"])
-    e_u = (z * p["a_u"]).sum(-1)                       # [N_src, H]
-    e_v = (z * p["a_v"]).sum(-1)
-    n_dst = nbr_idx.shape[0]
-    if use_kernel:
-        from repro.kernels import ops as kops
-        h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid)
-    else:
-        idx = jnp.maximum(nbr_idx, 0)
-        mask = (nbr_idx >= 0) & valid[idx]             # [N_dst, f]
-        scores = jax.nn.leaky_relu(
-            e_u[idx] + e_v[:n_dst, None, :], 0.2)      # [N_dst, f, H]
-        scores = jnp.where(mask[..., None], scores, -1e30)
-        alpha = jax.nn.softmax(scores, axis=1)
-        alpha = jnp.where(mask[..., None], alpha, 0.0)
-        h = jnp.einsum("nfh,nfhe->nhe", alpha, z[idx])  # [N_dst, H, dh]
-    return h.reshape(n_dst, -1)
+def gat_layer(p, h_src, nbr_idx, valid, *, layer: int, use_kernel=False):
+    """h_src [N_src, din] -> h_dst [N_dst, H*dh] (pre-dropout); ``layer``
+    names its scopes."""
+    with jax.named_scope(f"layer{layer}_update"):
+        z = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h_src, p["w"]) + p["b"])
+    with jax.named_scope(f"layer{layer}_aggregate"):
+        e_u = (z * p["a_u"]).sum(-1)                       # [N_src, H]
+        e_v = (z * p["a_v"]).sum(-1)
+        n_dst = nbr_idx.shape[0]
+        if use_kernel:
+            from repro.kernels import ops as kops
+            h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid)
+        else:
+            idx = jnp.maximum(nbr_idx, 0)
+            mask = (nbr_idx >= 0) & valid[idx]             # [N_dst, f]
+            scores = jax.nn.leaky_relu(
+                e_u[idx] + e_v[:n_dst, None, :], 0.2)      # [N_dst, f, H]
+            scores = jnp.where(mask[..., None], scores, -1e30)
+            alpha = jax.nn.softmax(scores, axis=1)
+            alpha = jnp.where(mask[..., None], alpha, 0.0)
+            h = jnp.einsum("nfh,nfhe->nhe", alpha, z[idx])  # [N_dst, H, dh]
+        return h.reshape(n_dst, -1)
 
 
 def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
@@ -68,7 +71,7 @@ def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
     for k in range(L):
         nbr = blocks["nbr_idx"][k]
         h_new = gat_layer(params["layers"][k], h, nbr, valid,
-                          use_kernel=use_kernel)
+                          use_kernel=use_kernel, layer=k)
         last = k == L - 1
         if not last and dropout > 0:
             h_new = hash_dropout(h_new, dropout, seed + jnp.uint32(k + 1))

@@ -65,14 +65,17 @@ def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
     L = len(params["layers"])
     for k in range(L):
         nbr = blocks["nbr_idx"][k]
-        feats, mask = gather_neighbors(h, nbr, valid)
-        agg = masked_mean(feats, mask)
+        with jax.named_scope(f"layer{k}_aggregate"):
+            feats, mask = gather_neighbors(h, nbr, valid)
+            agg = masked_mean(feats, mask)
         n_dst = nbr.shape[0]
         self_h = h[:n_dst]
         last = k == L - 1
-        h_new = update(params["layers"][k], agg, self_h,
-                       relu=not last, dropout=0.0 if last else dropout,
-                       seed=seed + jnp.uint32(k + 1), use_kernel=use_kernel)
+        with jax.named_scope(f"layer{k}_update"):
+            h_new = update(params["layers"][k], agg, self_h,
+                           relu=not last, dropout=0.0 if last else dropout,
+                           seed=seed + jnp.uint32(k + 1),
+                           use_kernel=use_kernel)
         valid = valid[:n_dst]
         if halo_hook is not None and not last:
             h_new, valid = halo_hook(k + 1, h_new, valid)

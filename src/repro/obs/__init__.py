@@ -15,6 +15,11 @@ One process-wide :class:`Observability` runtime (swap it with
     with per-rank thread-aware nesting, exported as Chrome trace-event
     JSON (load in chrome://tracing / Perfetto).  **Opt-in**
     (``ObsConfig(trace=True)`` or ``--trace-out`` on the launchers),
+  * the ``jax.profiler`` host plane: every span is also a
+    ``jax.profiler.TraceAnnotation`` for its lifetime, so a profiler trace
+    (``jax.profiler.start_trace``, TensorBoard / Perfetto) shows it by
+    name on the thread that ran it, on the same clock as the device ops.
+    Outside a profiler trace the annotation costs one enter/exit,
   * the :class:`EpochBreakdown` / :class:`StepModel` report: per-epoch
     sample / host-prep / H2D / forward / AEP-push / backward shares and
     the overlap-efficiency figure (fraction of modeled push latency
@@ -44,7 +49,8 @@ Instrumented code calls the module-level helpers::
     obs.count("halo_fetched", n, subsystem="serve")
 
 With everything disabled (``ObsConfig(enabled=False)``) every helper
-short-circuits to shared no-op objects: zero allocation per call, and —
+short-circuits to shared no-op objects: zero allocation per call, no
+profiler annotation, and —
 because observability only ever *reads* timings and host counters — the
 computed outputs are bit-identical with obs on, off, or tracing
 (pinned in ``tests/test_obs.py``).
@@ -54,6 +60,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import List, Optional
+
+import jax
 
 from repro.obs.breakdown import (EpochBreakdown, MEASURED_PHASES,  # noqa: F401
                                  REPORT_PHASES, StepModel)
@@ -106,8 +114,10 @@ _NULL_SPAN = _NullSpan()
 
 class _PhaseSpan:
     """Times one phase: accumulates ``phase_seconds{phase=<name>}`` in the
-    registry (when enabled) and records a trace event (when tracing)."""
-    __slots__ = ("_obs", "_name", "_args", "_t0")
+    registry (when enabled), records a trace event (when tracing), and
+    holds a ``jax.profiler.TraceAnnotation`` of the same name open, so a
+    profiler trace shows the phase on the thread that ran it."""
+    __slots__ = ("_obs", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, runtime: "Observability", name: str, args: dict):
         self._obs = runtime
@@ -117,10 +127,15 @@ class _PhaseSpan:
     def __enter__(self):
         if self._obs.tracer.enabled:
             self._obs.tracer.push(self._name)
+        # the annotation nests inside the timed interval, so the profiler's
+        # span never outlasts the registry's
         self._t0 = time.perf_counter()
+        self._annotation = jax.profiler.TraceAnnotation(self._name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         t1 = time.perf_counter()
         o = self._obs
         if o.registry.enabled:

@@ -14,6 +14,11 @@ Tracing is opt-in (``ObsConfig(trace=True)``); a disabled tracer is never
 consulted — the combined ``obs.span`` returns a shared no-op context
 manager, so the instrumented hot paths pay nothing.
 
+This JSON is the operator's file (``--trace-out``).  Independently of it,
+every ``obs.span`` also lands on the ``jax.profiler`` host plane as a
+``TraceAnnotation`` of the same name (see ``repro.obs``), so a profiler
+trace of a run shows the spans beside the device ops on one clock.
+
 ``add_complete`` records *modeled* spans (explicit start/duration on a
 named virtual thread) — how ``gnn_dryrun --trace-out`` draws its roofline
 decomposition (fwd / aep_push / bwd) without executing a step.
@@ -100,11 +105,6 @@ class Tracer:
         if args:
             ev["args"] = dict(args)
         self.events.append(ev)
-
-    def counter_event(self, name: str, when_s: float, values: dict):
-        """Chrome "C" counter event (e.g. queue depth over trace time)."""
-        self.events.append({"name": name, "ph": "C", "ts": when_s * 1e6,
-                            "pid": self.rank, "args": dict(values)})
 
     # -- export --------------------------------------------------------------
     def export(self) -> dict:

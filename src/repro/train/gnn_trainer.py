@@ -284,23 +284,28 @@ class DistTrainer:
         # (1) HEC tick + consume the delayed push (paper lines 8-9); the
         # hot tier ticks/consumes its broadcast segment the same way
         if self.mode == "aep":
-            if hot:
-                hec, hot = self.engine.consume_push(
-                    hec, inflight, dims, cfg.hec.life_span, hot=hot)
-            else:
-                hec = self.engine.consume_push(hec, inflight, dims,
-                                               cfg.hec.life_span)
+            with jax.named_scope("hec_store"):
+                if hot:
+                    hec, hot = self.engine.consume_push(
+                        hec, inflight, dims, cfg.hec.life_span, hot=hot)
+                else:
+                    hec = self.engine.consume_push(hec, inflight, dims,
+                                                   cfg.hec.life_span)
 
         # (2) layer-0 inputs
         nodes0 = mb["layer_nodes"][0]
         mask0 = mb["node_mask"][0]
         is_halo0 = (nodes0 >= num_solid) & mask0
-        solid_idx = jnp.clip(nodes0, 0, data["features"].shape[0] - 1)
-        h0 = data["features"][solid_idx] * (mask0 & ~is_halo0)[:, None]
+        with jax.named_scope("feature_gather"):
+            solid_idx = jnp.clip(nodes0, 0, data["features"].shape[0] - 1)
+            h0 = data["features"][solid_idx] * (mask0 & ~is_halo0)[:, None]
         valid0 = mask0 & ~is_halo0
-        vid_o_nodes = [jnp.where(n >= 0,
-                                 data["vid_o"][jnp.clip(n, 0, P_max - 1)], -1)
-                       for n in mb["layer_nodes"]]
+        # the HEC's keys: every sampled row's original vertex id
+        with jax.named_scope("hec_lookup"):
+            vid_o_nodes = [
+                jnp.where(n >= 0, data["vid_o"][jnp.clip(n, 0, P_max - 1)],
+                          -1)
+                for n in mb["layer_nodes"]]
 
         def tier_sub(k, h, is_halo):
             """Hot-tier substitution: a halo row whose hub embedding is
@@ -316,10 +321,11 @@ class DistTrainer:
 
         zero = jnp.zeros((), jnp.int32)
         if self.mode == "aep":
-            h0, use_hot0 = tier_sub(0, h0, is_halo0)
-            hit0, emb0 = hec_lib.hec_lookup(hec[0], vid_o_nodes[0])
-            use0 = is_halo0 & hit0 & ~use_hot0
-            h0 = jnp.where(use0[:, None], emb0, h0)
+            with jax.named_scope("hec_lookup"):
+                h0, use_hot0 = tier_sub(0, h0, is_halo0)
+                hit0, emb0 = hec_lib.hec_lookup(hec[0], vid_o_nodes[0])
+                use0 = is_halo0 & hit0 & ~use_hot0
+                h0 = jnp.where(use0[:, None], emb0, h0)
             valid0 = valid0 | use0 | use_hot0
             hits0 = (jnp.sum(use0 | use_hot0), jnp.sum(is_halo0),
                      jnp.sum(use_hot0))
@@ -351,10 +357,11 @@ class DistTrainer:
                 maskk = mb["node_mask"][k]
                 is_halo = (nodes_k >= num_solid) & maskk
                 if self.mode == "aep" and k < L:
-                    h, use_hot = tier_sub(k, h, is_halo)
-                    hit, emb = hec_lib.hec_lookup(hec[k], vid_o_nodes[k])
-                    use = is_halo & hit & ~use_hot
-                    h = jnp.where(use[:, None], emb[:, :h.shape[1]], h)
+                    with jax.named_scope("hec_lookup"):
+                        h, use_hot = tier_sub(k, h, is_halo)
+                        hit, emb = hec_lib.hec_lookup(hec[k], vid_o_nodes[k])
+                        use = is_halo & hit & ~use_hot
+                        h = jnp.where(use[:, None], emb[:, :h.shape[1]], h)
                     valid = (valid & ~is_halo) | use | use_hot
                     hits.append((jnp.sum(use | use_hot), jnp.sum(is_halo),
                                  jnp.sum(use_hot)))
@@ -368,16 +375,17 @@ class DistTrainer:
             out, valid = _forward(cfg, params, h0, valid0, blocks,
                                   cfg.dropout, seed, halo_hook,
                                   self.use_kernel)
-            B = mb["seeds"].shape[0]
-            logits = out[:B].astype(jnp.float32)
-            lmask = mb["seed_mask"] & valid[:B]
-            labels = mb["labels"]
-            logz = jax.scipy.special.logsumexp(logits, -1)
-            gold = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
-            nll = (logz - gold) * lmask
-            n_valid = lmask.sum()
-            loss = nll.sum() / jnp.maximum(n_valid, 1)
-            correct = ((jnp.argmax(logits, -1) == labels) & lmask).sum()
+            with jax.named_scope("loss"):
+                B = mb["seeds"].shape[0]
+                logits = out[:B].astype(jnp.float32)
+                lmask = mb["seed_mask"] & valid[:B]
+                labels = mb["labels"]
+                logz = jax.scipy.special.logsumexp(logits, -1)
+                gold = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
+                nll = (logz - gold) * lmask
+                n_valid = lmask.sum()
+                loss = nll.sum() / jnp.maximum(n_valid, 1)
+                correct = ((jnp.argmax(logits, -1) == labels) & lmask).sum()
             return loss, (nll.sum(), correct, n_valid, captured, hits)
 
         # (3) backward + AEP push (paper lines 14-24).  The push depends
@@ -435,9 +443,10 @@ class DistTrainer:
         loss_m = jax.lax.psum(nll_sum, "data") / denom
         acc_m = jax.lax.psum(correct, "data") / denom
 
-        new_params, new_opt, diag = opt_lib.adam_update(
-            grads, opt_state, params,
-            opt_lib.AdamConfig(lr=cfg.lr, grad_clip=1.0))
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, diag = opt_lib.adam_update(
+                grads, opt_state, params,
+                opt_lib.AdamConfig(lr=cfg.lr, grad_clip=1.0))
         grad_norm = diag["grad_norm"]
         skipped = None
         if fcode is None:
@@ -586,25 +595,32 @@ class DistTrainer:
         s_policy = cfg.pipeline.sampler.policy
         with guard:
             for ep in range(start_epoch, start_epoch + num_epochs):
-                if (pipeline is not None and s_policy == "cv"
-                        and cfg.pipeline.sampler.device_draw):
-                    # control-variate sampling: refresh the per-rank HEC
-                    # residency the cv draw weights read — vertices with a
-                    # live historical activation get preferred at sample
-                    # time (arxiv 1710.10568), and the set tracked here is
-                    # exactly what the epoch's lookups can hit
-                    pipeline.set_cv_residency(
-                        self._cv_residency(ps, state))
-                if pipeline is not None:
-                    mb_iter = pipeline.epoch_batches(ep)
-                else:
-                    from repro.train.data import gnn_epoch_iterator
-                    mb_iter = (mb for mb, _ in
-                               gnn_epoch_iterator(ps, cfg, rng))
+                ph0, wall0 = phase_at(), time.perf_counter()
+                # the loop thread's spans tile the epoch: epoch_fill (up to
+                # the first minibatch in hand), then per step the step span
+                # and the batch_wait for the next minibatch, then epoch_end
+                with obs.span("epoch_fill", epoch=ep):
+                    if (pipeline is not None and s_policy == "cv"
+                            and cfg.pipeline.sampler.device_draw):
+                        # control-variate sampling: refresh the per-rank
+                        # HEC residency the cv draw weights read — vertices
+                        # with a live historical activation get preferred
+                        # at sample time (arxiv 1710.10568), and the set
+                        # tracked here is exactly what the epoch's lookups
+                        # can hit
+                        pipeline.set_cv_residency(
+                            self._cv_residency(ps, state))
+                    if pipeline is not None:
+                        mb_iter = pipeline.epoch_batches(ep)
+                    else:
+                        from repro.train.data import gnn_epoch_iterator
+                        mb_iter = (mb for mb, _ in
+                                   gnn_epoch_iterator(ps, cfg, rng))
+                    mb = next(mb_iter, None)
                 ep_metrics = []
                 t_step_ep = 0.0
-                ph0, wall0 = phase_at(), time.perf_counter()
-                for k_ep, mb in enumerate(mb_iter):
+                k_ep = 0
+                while mb is not None:
                     # the span covers dispatch AND the blocking host
                     # transfer of the metrics — i.e. the device step's wall
                     # time as seen by the training loop
@@ -623,6 +639,12 @@ class DistTrainer:
                             state["params"], state["opt_state"],
                             state["hec"], state["hot"], state["inflight"],
                             dist_data, mb, jnp.uint32(step_idx), *fargs)
+                        # one device-to-host transfer for everything the
+                        # host reads of the step
+                        with obs.span("step_sync"):
+                            metrics, rank_stats = jax.device_get(
+                                (metrics,
+                                 rank_stats if acc is not None else None))
                         ep_metrics.append(
                             {k_: float(v) for k_, v in metrics.items()})
                     t_step_ep += time.perf_counter() - ts0
@@ -630,67 +652,74 @@ class DistTrainer:
                         rz.on_step(ep, k_ep,
                                    ep_metrics[-1].get("skipped", 0.0))
                     if acc is not None:
-                        acc.add(jax.tree_util.tree_map(np.asarray,
-                                                       rank_stats))
+                        acc.add(rank_stats)
                     step_idx += 1
-                mean = _epoch_mean(ep_metrics)
-                # annotate which fanout-draw policy produced the epoch so
-                # downstream consumers (history rows, the labeled counter)
-                # can attribute convergence/perf deltas to the sampler
-                mean["sampler_policy"] = s_policy
-                wall = time.perf_counter() - wall0
-                if reg.enabled:
-                    reg.counter("train_epochs_total",
-                                sampler_policy=s_policy).inc()
-                    # per-epoch phase seconds (sample/host_prep run on the
-                    # prefetch workers, so an epoch is credited with
-                    # whatever preparation completed during it — exact at
-                    # depth 1); EpochBreakdown.from_history renders the
-                    # paper table
-                    ph1 = phase_at()
-                    for p in phases:
-                        mean[f"t_{p}"] = ph1[p] - ph0[p]
-                    mean["t_wall"] = wall
-                if acc is not None:
-                    totals = acc.finish()
-                    # in-process shard_map has ONE clock for the fused
-                    # program, so every rank is credited the same step
-                    # wall time; multi-host deployments feed real per-rank
-                    # timings here and the straggler detector bites
-                    totals["rank_step_seconds"] = np.full(
-                        self.num_ranks, t_step_ep, np.float64)
+                    k_ep += 1
+                    with obs.span("batch_wait", epoch=ep):
+                        mb = next(mb_iter, None)
+                with obs.span("epoch_end", epoch=ep):
+                    mean = _epoch_mean(ep_metrics)
+                    # annotate which fanout-draw policy produced the epoch so
+                    # downstream consumers (history rows, the labeled counter)
+                    # can attribute convergence/perf deltas to the sampler
+                    mean["sampler_policy"] = s_policy
+                    wall = time.perf_counter() - wall0
                     if reg.enabled:
-                        obs.publish_rank_series(reg, totals)
-                    if health:
-                        health.observe_epoch(totals, wall_s=wall)
-                if quality:
-                    # instruments 1+3: staleness read off the live device
-                    # state (one host transfer per layer), convergence
-                    # point into the event log.  Instrument 2 (the audit,
-                    # an extra offline forward pass) only on its interval.
-                    quality.observe_epoch(ep, metrics=mean)
-                    quality.publish_staleness(state["hec"])
-                    if state["hot"]:
-                        hot_lib.publish_replica_ages(
-                            state["hot"], life_span=cfg.hec.life_span)
-                    if quality.should_audit(ep):
-                        self.audit(ps, dist_data, state, epoch=ep)
-                history.append(mean)
-                if rz is not None and getattr(rz, "ckpt", None) is not None:
-                    # epoch-boundary checkpoint of the FULL state pytree
-                    # (params, opt state, HEC, hot tier, inflight queue).
-                    # state["step"] is stamped first so a resumed run
-                    # continues the device-seed sequence bit-exactly.
-                    state["step"] = jnp.asarray(step_idx, jnp.int32)
-                    rz.maybe_checkpoint(state, ep)
-                if log_every:
-                    hl = [f"l{l}:{mean.get(f'hec_hits_l{l}', 0)/max(mean.get(f'hec_halos_l{l}',1),1):.2f}"
-                          for l in range(cfg.num_layers)]
-                if log_every and (ep % log_every == 0
-                                  or ep == start_epoch + num_epochs - 1):
-                    print(f"[{self.mode}] epoch {ep}: "
-                          f"loss={mean['loss']:.4f} "
-                          f"acc={mean['acc']:.3f} hit-rates {' '.join(hl)}")
+                        reg.counter("train_epochs_total",
+                                    sampler_policy=s_policy).inc()
+                        # per-epoch phase seconds (sample/host_prep run on the
+                        # prefetch workers, so an epoch is credited with
+                        # whatever preparation completed during it — exact at
+                        # depth 1); EpochBreakdown.from_history renders the
+                        # paper table
+                        ph1 = phase_at()
+                        for p in phases:
+                            mean[f"t_{p}"] = ph1[p] - ph0[p]
+                        mean["t_wall"] = wall
+                    if acc is not None:
+                        totals = acc.finish()
+                        # in-process shard_map has ONE clock for the fused
+                        # program, so every rank is credited the same step
+                        # wall time; multi-host deployments feed real per-rank
+                        # timings here and the straggler detector bites
+                        totals["rank_step_seconds"] = np.full(
+                            self.num_ranks, t_step_ep, np.float64)
+                        if reg.enabled:
+                            obs.publish_rank_series(reg, totals)
+                        if health:
+                            health.observe_epoch(totals, wall_s=wall)
+                    if quality:
+                        # instruments 1+3: staleness read off the live device
+                        # state (one host transfer per layer), convergence
+                        # point into the event log.  Instrument 2 (the audit,
+                        # an extra offline forward pass) only on its interval.
+                        quality.observe_epoch(ep, metrics=mean)
+                        quality.publish_staleness(state["hec"])
+                        if state["hot"]:
+                            hot_lib.publish_replica_ages(
+                                state["hot"], life_span=cfg.hec.life_span)
+                        if quality.should_audit(ep):
+                            self.audit(ps, dist_data, state, epoch=ep)
+                    history.append(mean)
+                    if rz is not None \
+                            and getattr(rz, "ckpt", None) is not None:
+                        # epoch-boundary checkpoint of the FULL state pytree
+                        # (params, opt state, HEC, hot tier, inflight queue).
+                        # state["step"] is stamped first so a resumed run
+                        # continues the device-seed sequence bit-exactly.
+                        state["step"] = jnp.asarray(step_idx, jnp.int32)
+                        rz.maybe_checkpoint(state, ep)
+                    if log_every and (ep % log_every == 0
+                                      or ep == start_epoch + num_epochs - 1):
+                        hl = " ".join(
+                            f"l{l}:" + format(
+                                mean.get(f"hec_hits_l{l}", 0)
+                                / max(mean.get(f"hec_halos_l{l}", 1), 1),
+                                ".2f")
+                            for l in range(cfg.num_layers))
+                        print(f"[{self.mode}] epoch {ep}: "
+                              f"loss={mean['loss']:.4f} "
+                              f"acc={mean['acc']:.3f} hit-rates {hl}")
         state["step"] = jnp.asarray(step_idx, jnp.int32)
         if rz is not None:
             # one FLIGHT_resilience.json per run that saw faults or skips,

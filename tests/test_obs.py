@@ -3,8 +3,14 @@ Chrome trace schema, hit-rate derivation, the epoch breakdown, and the
 bit-identity contract — training steps and serve rounds compute the same
 bits with observability off, on, or tracing (spans only *read* timings
 and host counters; they never feed back into the numerics)."""
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
 import threading
+import time
 
 import jax
 import numpy as np
@@ -255,6 +261,47 @@ def test_disabled_obs_is_a_shared_noop():
     assert obs.get().tracer.events == []
 
 
+def _profiled_spans(trace_dir):
+    """{name: [(line id, start_ns, end_ns)]} of the host planes' events."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (i, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """Each span is a profiler annotation of the same name, on the thread
+    that ran it, lasting no longer than the registry's perf_counter
+    interval; a fully disabled runtime leaves no annotation."""
+    jax.profiler.start_trace(str(tmp_path / "on"))
+    with obs.span("outer_phase"):
+        time.sleep(0.02)
+        with obs.span("inner_phase"):
+            time.sleep(0.01)
+    jax.profiler.stop_trace()
+    timed = obs.get().phase_seconds("outer_phase")
+    obs.configure(obs.ObsConfig(enabled=False))
+    jax.profiler.start_trace(str(tmp_path / "off"))
+    with obs.span("silent_phase"):
+        time.sleep(0.01)
+    jax.profiler.stop_trace()
+
+    got = _profiled_spans(str(tmp_path / "on"))
+    (lo, o_s, o_e), = got["outer_phase"]
+    (li, i_s, i_e), = got["inner_phase"]
+    assert lo == li and o_s <= i_s and i_e <= o_e
+    assert 0.03 <= (o_e - o_s) * 1e-9 <= timed
+    assert "silent_phase" not in _profiled_spans(str(tmp_path / "off"))
+
+
 # -- breakdown ---------------------------------------------------------------
 def test_step_model_roofline_and_overlap():
     m = obs.StepModel.from_roofline(
@@ -331,6 +378,86 @@ def test_train_step_bit_identical_under_tracing(tiny_train):
     names = {e["name"] for e in trace["traceEvents"] if e["ph"] == "X"}
     assert {"sample", "host_prep", "stage", "step"} <= names
     assert n > 0
+
+
+LOOP_SPANS = ("epoch_fill", "batch_wait", "step", "epoch_end")
+
+
+def test_loop_spans_tile_train_epochs(tiny_train, monkeypatch):
+    """The step loop's own spans cover the epochs' wall time, epoch_fill
+    opens once an epoch, and the host reads each step back in one
+    transfer (step_sync, nested in step)."""
+    ps, dd, tr, step_fn = tiny_train
+    state = tr.init_state(jax.random.key(0))
+    tr.train_epochs(ps, dd, state, 1, step_fn=step_fn)     # compile
+    obs.configure()
+    gets = []
+    real_get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: gets.append(1) or real_get(x))
+    state = tr.init_state(jax.random.key(0))
+    t0 = time.perf_counter()
+    state, hist = tr.train_epochs(ps, dd, state, 6, step_fn=step_fn)
+    wall = time.perf_counter() - t0
+    reg = obs.get().registry
+    steps = int(state["step"])
+    assert steps >= 12 and len(hist) == 6
+    covered = sum(reg.value("phase_seconds", phase=p) for p in LOOP_SPANS)
+    assert 0.9 * wall <= covered <= wall
+    assert reg.value("phase_calls", phase="epoch_fill") == 6
+    assert reg.value("phase_calls", phase="epoch_end") == 6
+    assert reg.value("phase_calls", phase="batch_wait") == steps
+    assert reg.value("phase_calls", phase="step_sync") == steps
+    assert reg.value("phase_seconds", phase="step_sync") \
+        <= reg.value("phase_seconds", phase="step")
+    assert len(gets) == steps
+
+
+_SCOPES_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json, re
+import jax
+import numpy as np
+from repro.configs.gnn import small_gnn_config
+from repro.graph import partition_graph, synthetic_graph
+from repro.launch.mesh import make_gnn_mesh
+from repro.pipeline.staging import MinibatchPipeline
+from repro.train.gnn_trainer import DistTrainer, build_dist_data
+
+g = synthetic_graph(num_vertices=400, avg_degree=5, num_classes=4,
+                    feat_dim=8, seed=0)
+ps = partition_graph(g, 2, seed=0)
+cfg = small_gnn_config(sys.argv[1], batch_size=16, feat_dim=8, num_heads=2,
+                       num_classes=4, fanouts=(3, 3), hidden_size=16)
+mesh = make_gnn_mesh(2)
+dd = build_dist_data(ps, cfg, mesh)
+tr = DistTrainer(cfg=cfg, mesh=mesh, num_ranks=2, mode="aep")
+state = tr.init_state(jax.random.key(0), dd)
+mb = next(iter(MinibatchPipeline(ps, cfg, mesh=mesh).epoch_batches(0)))
+text = tr.make_step(dd).lower(
+    state["params"], state["opt_state"], state["hec"], state["hot"],
+    state["inflight"], dd, mb, np.uint32(0)).compile().as_text()
+print(json.dumps(sorted(set(re.findall(r'op_name="([^"]+)"', text)))))
+"""
+
+SCOPES = ("feature_gather", "hec_lookup", "hec_store", "layer0_aggregate",
+          "layer1_aggregate", "layer0_update", "layer1_update", "loss",
+          "optimizer", "aep_pack", "aep_exchange", "aep_consume")
+
+
+@pytest.mark.parametrize("model", ["graphsage", "gat"])
+def test_compiled_step_carries_the_named_scopes(model):
+    """The AEP step on two ranks, compiled: every named scope is in the
+    ops' op_name metadata, and backward ops inherit their layer's."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", _SCOPES_SCRIPT, model],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    names = json.loads(p.stdout.strip().splitlines()[-1])
+    tokens = {t for n in names for t in re.findall(r"\w+", n)}
+    assert set(SCOPES) <= tokens
+    assert any("transpose(jvp(layer1_aggregate))" in n for n in names)
 
 
 def test_serve_round_bit_identical_under_tracing():
