@@ -1,9 +1,10 @@
 """On-device fanout draw (drops the host ``np.random`` sampling loop).
 
 The host vectorized sampler (pipeline/vectorized_sampler.py) draws
-without replacement via numpy argpartition over uniform keys.  The
-device path reformulates the same draw as a *selection-key* problem that
-runs entirely on-device:
+without replacement by Floyd's algorithm, one exact integer per pick;
+its take-all rows (deg <= fanout) keep every neighbor in CSR order.  The
+device path draws from the same distribution as a *selection-key*
+problem that runs entirely on-device:
 
   1. expand each frontier row's CSR neighbor range to a dense [n, W]
      candidate matrix (W = max degree), -1 past the row's degree,

@@ -6,10 +6,12 @@ pinning, but host sampling then dominates wall-clock and serializes against
 the device step.  This module produces the *same* fixed-shape
 ``MinibatchBlocks`` contract with no per-row Python loops:
 
-  * fanout draw: one uniform key matrix ``[n_dst, max_deg]`` per layer;
-    the ``f`` smallest keys of a row are a uniform sample without
-    replacement from that row's neighbors (rows with ``deg <= f`` keep all
-    neighbors in CSR order, matching the reference sampler).
+  * fanout draw: Floyd's algorithm over every row with ``deg > f`` at
+    once, ``f`` numpy passes per layer that each draw one exact integer
+    per row; a row's picks are a uniform sample without replacement from
+    its neighbors, at a cost that does not grow with its degree (rows
+    with ``deg <= f`` keep all neighbors in CSR order, matching the
+    reference sampler).
   * relabeling: ``np.unique``/``np.setdiff1d`` for the new-leaf set and an
     ``argsort`` + ``searchsorted`` lookup instead of a Python dict.
 
@@ -33,7 +35,15 @@ def _draw_neighbors(indptr: np.ndarray, indices: np.ndarray, cur: np.ndarray,
                     num_solid: int, f: int,
                     rng: np.random.Generator,
                     allow: Optional[np.ndarray] = None) -> np.ndarray:
-    """Sampled neighbor VIDs ``[len(cur), f]`` (-1 pad), no Python loops.
+    """Sampled neighbor VIDs ``[len(cur), f]`` (-1 pad), no per-row loops.
+
+    A row with ``deg <= f`` takes every neighbor in CSR order, left-packed,
+    and draws nothing.  A row with ``deg > f`` takes a uniform ``f``-subset
+    of its neighbors by Floyd's algorithm: for ``m = 0 .. f-1``, draw
+    ``t`` uniform in ``[0, deg-f+m]`` and keep ``t``, or ``deg-f+m`` where
+    ``t`` was already kept.  The loop runs over the ``f`` picks, each pass
+    covering all rows.  Each call adds its active rows to the counter
+    ``sample_rows{path=draw|all}``.
 
     ``allow`` (bool ``[len(cur)]``) suppresses expansion of individual rows:
     a row with ``allow=False`` keeps an all ``-1`` neighbor list, exactly as
@@ -41,6 +51,7 @@ def _draw_neighbors(indptr: np.ndarray, indices: np.ndarray, cur: np.ndarray,
     into leaves — their embedding is substituted from the HEC, so their
     neighborhood never needs to be materialized.
     """
+    from repro import obs       # lazy: module stays importable w/o jax
     n_dst = len(cur)
     out = np.full((n_dst, f), -1, np.int64)
     valid = (cur >= 0) & (cur < num_solid)        # halos are never expanded
@@ -65,23 +76,22 @@ def _draw_neighbors(indptr: np.ndarray, indices: np.ndarray, cur: np.ndarray,
         gi = np.minimum(ss[:, None] + col[None, :], len(indices) - 1)
         out[act[small], :w] = np.where(in_row, indices[gi], -1)
 
-    # deg > f rows: f smallest of iid uniform keys == uniform sample w/o
-    # replacement; all f picks are in-row so no masking/packing needed.
-    # Rows are processed in degree-sorted chunks so a few hub vertices don't
-    # widen the key matrix (and the argpartition) for every row.
+    # deg > f rows: Floyd's draw (docstring); all f picks are in-row
     big = ~small
-    if big.any():
-        rows, db, sb = act[big], deg[big], starts[big]
-        order = np.argsort(db, kind="stable")
-        for ch in np.array_split(order, min(8, len(order))):
-            if not len(ch):
-                continue
-            d_ch = db[ch]
-            w = int(d_ch.max())
-            keys = rng.random((len(ch), w), dtype=np.float32)
-            keys[np.arange(w)[None, :] >= d_ch[:, None]] = np.inf
-            sel = np.argpartition(keys, f - 1, axis=1)[:, :f]
-            out[rows[ch]] = indices[sb[ch][:, None] + sel]
+    n_big = int(big.sum())
+    obs.count("sample_rows", n_big, path="draw")
+    obs.count("sample_rows", len(act) - n_big, path="all")
+    if n_big:
+        # in-row positions are int32, one pick per row and pass
+        db = deg[big].astype(np.int32)
+        sel = np.empty((f, n_big), np.int32)
+        for m in range(f):
+            j = db - (f - m)
+            t = rng.integers(0, j + 1, dtype=np.int32)
+            if m:
+                np.copyto(t, j, where=(sel[:m] == t).any(0))
+            sel[m] = t
+        out[act[big]] = indices[starts[big][:, None] + sel.T]
     return out
 
 

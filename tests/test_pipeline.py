@@ -15,7 +15,7 @@ from repro.graph.sampling import (epoch_minibatches, layer_capacities,
                                   sample_blocks)
 from repro.pipeline import (MinibatchPipeline, SamplingPlan, prefetch,
                             sample_blocks_vectorized, stack_ranks)
-from repro.pipeline.vectorized_sampler import concat_blocks
+from repro.pipeline.vectorized_sampler import _draw_neighbors, concat_blocks
 
 FANOUTS = (4, 6)
 BATCH = 32
@@ -262,6 +262,163 @@ def test_concat_blocks_fused_forward_bitmatch(part):
             o, v = run(m)
             np.testing.assert_array_equal(of[i * B:(i + 1) * B], o)
             np.testing.assert_array_equal(vf[i * B:(i + 1) * B], v)
+
+
+# ---------------------------------------------------------------------------
+# host fanout draw (``_draw_neighbors``, Floyd's algorithm)
+# ---------------------------------------------------------------------------
+def _csr(degrees, num_extra=0):
+    """CSR whose row ``v`` holds ``degrees[v]`` distinct neighbor VIDs, all
+    above the rows (``num_extra`` more VIDs past them act as halos)."""
+    indptr = np.zeros(len(degrees) + 1, np.int64)
+    indptr[1:] = np.cumsum(degrees)
+    base = len(degrees) + num_extra
+    indices = np.concatenate([base + 1000 * v + np.arange(d)
+                              for v, d in enumerate(degrees)])
+    return indptr, indices.astype(np.int64)
+
+
+def _row(indptr, indices, v):
+    return indices[indptr[v]:indptr[v + 1]]
+
+
+def test_host_draw_inclusion_share():
+    """Each neighbor of a degree-40 row is drawn with share f/deg: 20,000
+    independent draws of the row, f = 5, every share within 0.015 of
+    0.125 (about 6 standard deviations)."""
+    indptr, indices = _csr([40])
+    n, f = 20000, 5
+    out = _draw_neighbors(indptr, indices, np.zeros(n, np.int64), 1, f,
+                          np.random.default_rng(11))
+    row = _row(indptr, indices, 0)
+    share = (out[:, :, None] == row).sum((0, 1)) / n
+    np.testing.assert_allclose(share, f / len(row), atol=0.015)
+    assert (np.sort(out, 1)[:, 1:] != np.sort(out, 1)[:, :-1]).all()
+
+
+def test_host_draw_subsets_uniform():
+    """Every 2-subset of a degree-5 row comes out equally often: 10 pairs,
+    30,000 draws, each pair's share in [0.09, 0.11] (3,000 expected,
+    about 5.8 standard deviations either side)."""
+    indptr, indices = _csr([5])
+    n = 30000
+    out = _draw_neighbors(indptr, indices, np.zeros(n, np.int64), 1, 2,
+                          np.random.default_rng(12))
+    pairs, counts = np.unique(np.sort(out, 1), axis=0, return_counts=True)
+    assert len(pairs) == 10
+    assert set(pairs.ravel().tolist()) == set(_row(indptr, indices, 0))
+    np.testing.assert_allclose(counts / n, 0.1, atol=0.01)
+
+
+@pytest.mark.parametrize("f", [1, 3, 5])
+def test_host_draw_hub_row_distinct(f):
+    """A hub row of degree 1,000, beside rows of degree 2-8, draws f
+    distinct neighbors of its own; the small rows are undisturbed."""
+    degrees = [1000, 2, 4, 8, 5, 3]
+    indptr, indices = _csr(degrees)
+    cur = np.tile(np.arange(len(degrees)), 50)
+    out = _draw_neighbors(indptr, indices, cur, len(degrees), f,
+                          np.random.default_rng(f))
+    for r, v in enumerate(cur):
+        got = out[r][out[r] >= 0]
+        row = _row(indptr, indices, v)
+        assert len(got) == min(f, len(row))
+        assert len(set(got.tolist())) == len(got)
+        assert set(got.tolist()) <= set(row.tolist())
+    hub = out[cur == 0]
+    assert len(np.unique(hub)) > 10 * f      # draws spread over the hub
+
+
+def test_host_draw_leaves_stay_empty():
+    """Rows with ``allow=False``, halo rows and ``-1`` padding keep all
+    ``-1``; the other rows draw as usual."""
+    indptr, indices = _csr([12, 3, 9, 1], num_extra=4)
+    S, f = 4, 4
+    cur = np.array([0, -1, 5, 1, 2, 3, 7, -1, 0])       # 5, 7: halos
+    allow = np.array([True, True, True, True, False, True, True, True,
+                      False])
+    out = _draw_neighbors(indptr, indices, cur, S, f,
+                          np.random.default_rng(0), allow=allow)
+    assert out.shape == (len(cur), f)
+    empty = [1, 2, 4, 6, 7, 8]
+    assert (out[empty] == -1).all()
+    assert (out[0] >= 0).all()
+    np.testing.assert_array_equal(out[3], list(_row(indptr, indices, 1))
+                                  + [-1])
+    np.testing.assert_array_equal(out[5], list(_row(indptr, indices, 3))
+                                  + [-1] * 3)
+
+
+@pytest.mark.parametrize("f", [1, 2, 5, 10])
+def test_host_draw_degree_at_and_past_fanout(f):
+    """``deg == f`` takes the whole row in CSR order with no draw;
+    ``deg == f + 1`` leaves out one neighbor, each about equally often."""
+    indptr, indices = _csr([f, f + 1])
+    n = 4000
+    cur = np.repeat([0, 1], n)
+    out = _draw_neighbors(indptr, indices, cur, 2, f,
+                          np.random.default_rng(f))
+    np.testing.assert_array_equal(out[:n], np.tile(_row(indptr, indices, 0),
+                                                   (n, 1)))
+    past = out[n:]
+    row = _row(indptr, indices, 1)
+    assert (past >= 0).all()
+    assert (np.sort(past, 1)[:, 1:] != np.sort(past, 1)[:, :-1]).all()
+    left_out = n - (past[:, :, None] == row).sum((0, 1))
+    np.testing.assert_allclose(left_out / n, 1 / (f + 1), atol=0.04)
+
+
+@pytest.mark.parametrize("workers", [0, 1, 4])
+def test_plan_sample_host_same_any_worker_count(ps, workers):
+    """Host minibatches of ``SamplingPlan.sample_host`` are identical for
+    0 (inline), 1 and 4 prefetch workers: each step owns its stream."""
+    cfg = small_gnn_config("graphsage", batch_size=BATCH, feat_dim=8,
+                           num_classes=4, fanouts=FANOUTS)
+    plan = SamplingPlan(ps=ps, cfg=cfg, base_seed=4)
+    sched = plan.epoch_schedule(1)
+    n = min(len(sched), 6)
+
+    def make(step):
+        return plan.sample_host(1, step, sched[step])
+
+    want = [make(s) for s in range(n)]
+    got = list(prefetch(make, n, num_workers=workers, depth=2))
+    assert len(got) == n
+    for a, b in zip(want, got):
+        assert a.keys() == b.keys()
+        for key in a:
+            xs = a[key] if isinstance(a[key], list) else [a[key]]
+            ys = b[key] if isinstance(b[key], list) else [b[key]]
+            for x, y in zip(xs, ys, strict=True):
+                np.testing.assert_array_equal(x, y)
+
+
+def test_sample_rows_counter_adds_up(part, vec_mb):
+    """``sample_rows{path}`` grows by the rows each layer expands:
+    ``draw`` by those with ``deg > f``, ``all`` by the take-all rows."""
+    from repro import obs
+    obs.configure()
+    try:
+        reg = obs.get().registry
+        deg = part.indptr[1:] - part.indptr[:-1]
+        seen = []
+        for k, f in enumerate(FANOUTS):
+            cur = vec_mb.layer_nodes[k + 1]
+            solid = (cur >= 0) & (cur < part.num_solid)
+            d = np.where(solid, deg[np.where(solid, cur, 0)], 0)
+            before = {p: reg.value("sample_rows", path=p)
+                      for p in ("draw", "all")}
+            _draw_neighbors(part.indptr, part.indices, cur, part.num_solid,
+                            f, np.random.default_rng(k))
+            drawn = reg.value("sample_rows", path="draw") - before["draw"]
+            took = reg.value("sample_rows", path="all") - before["all"]
+            assert drawn == (d > f).sum()
+            assert took == ((d > 0) & (d <= f)).sum()
+            assert drawn + took == (d > 0).sum()
+            seen += [drawn, took]
+        assert min(seen[0::2]) > 0 and max(seen[1::2]) > 0
+    finally:
+        obs.configure()
 
 
 # ---------------------------------------------------------------------------
