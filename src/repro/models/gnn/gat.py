@@ -39,25 +39,33 @@ def init_params(key, feat_dim: int, hidden: int, num_classes: int,
 
 def gat_layer(p, h_src, nbr_idx, valid, *, layer: int, use_kernel=False):
     """h_src [N_src, din] -> h_dst [N_dst, H*dh] (pre-dropout); ``layer``
-    names its scopes."""
+    names its scopes.  Inside ``layer{k}_aggregate`` the attention's parts
+    are scoped as ``edge_scores`` (e_u/e_v, LeakyReLU, mask),
+    ``edge_softmax`` and ``edge_gather_sum`` (z[idx] and the weighted
+    sum)."""
     with jax.named_scope(f"layer{layer}_update"):
         z = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h_src, p["w"]) + p["b"])
     with jax.named_scope(f"layer{layer}_aggregate"):
-        e_u = (z * p["a_u"]).sum(-1)                       # [N_src, H]
-        e_v = (z * p["a_v"]).sum(-1)
+        with jax.named_scope("edge_scores"):
+            e_u = (z * p["a_u"]).sum(-1)                   # [N_src, H]
+            e_v = (z * p["a_v"]).sum(-1)
         n_dst = nbr_idx.shape[0]
         if use_kernel:
             from repro.kernels import ops as kops
             h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid)
         else:
             idx = jnp.maximum(nbr_idx, 0)
-            mask = (nbr_idx >= 0) & valid[idx]             # [N_dst, f]
-            scores = jax.nn.leaky_relu(
-                e_u[idx] + e_v[:n_dst, None, :], 0.2)      # [N_dst, f, H]
-            scores = jnp.where(mask[..., None], scores, -1e30)
-            alpha = jax.nn.softmax(scores, axis=1)
-            alpha = jnp.where(mask[..., None], alpha, 0.0)
-            h = jnp.einsum("nfh,nfhe->nhe", alpha, z[idx])  # [N_dst, H, dh]
+            with jax.named_scope("edge_scores"):
+                mask = (nbr_idx >= 0) & valid[idx]         # [N_dst, f]
+                scores = jax.nn.leaky_relu(
+                    e_u[idx] + e_v[:n_dst, None, :], 0.2)  # [N_dst, f, H]
+                scores = jnp.where(mask[..., None], scores, -1e30)
+            with jax.named_scope("edge_softmax"):
+                alpha = jax.nn.softmax(scores, axis=1)
+                alpha = jnp.where(mask[..., None], alpha, 0.0)
+            with jax.named_scope("edge_gather_sum"):
+                h = jnp.einsum("nfh,nfhe->nhe", alpha,
+                               z[idx])                     # [N_dst, H, dh]
         return h.reshape(n_dst, -1)
 
 
