@@ -9,13 +9,24 @@ applied to the projection BEFORE computing attention coefficients):
 The per-head broadcast edge-softmax aggregation is the operation the paper
 adds SIMD broadcast support for (LIBXSMM); the Pallas analogue is
 kernels/gat_edge.py.
+
+Each layer picks the order of projection and neighbour gather from its
+widths (``gat_layer``).  Where the input is narrower than the projection
+(``din < H*dh``) it gathers each sampled edge's input and projects it there;
+otherwise it projects every source row once and gathers the projection.
+The projection acts row by row, so both orders give the same ``z`` per
+edge.  On the paper's GAT only layer 0 gathers first (128-wide features
+against 4 heads x 256): its input is the minibatch's features, which need
+no gradient, so no scatter-add of per-edge gradients back into source rows
+is left in its backward.  The hidden layers project first: layer 1's input
+is as wide as its projection, and layer 2's is wider.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.gnn.common import gather_neighbors, hash_dropout
+from repro.models.gnn.common import hash_dropout
 
 
 def init_params(key, feat_dim: int, hidden: int, num_classes: int,
@@ -39,34 +50,68 @@ def init_params(key, feat_dim: int, hidden: int, num_classes: int,
 
 def gat_layer(p, h_src, nbr_idx, valid, *, layer: int, use_kernel=False):
     """h_src [N_src, din] -> h_dst [N_dst, H*dh] (pre-dropout); ``layer``
-    names its scopes.  Inside ``layer{k}_aggregate`` the attention's parts
-    are scoped as ``edge_scores`` (e_u/e_v, LeakyReLU, mask),
-    ``edge_softmax`` and ``edge_gather_sum`` (z[idx] and the weighted
-    sum)."""
+    names its scopes.  Destination rows are the prefix of the source rows.
+
+    Order, from the widths: where ``din < H*dh`` the layer gathers each
+    edge's ``h_src[idx]`` and projects it (and the destination rows) under
+    ``layer{k}_update``, so the gather, and any scatter-add in its
+    backward, runs at the narrower width; an input that needs no gradient
+    (layer 0's features) leaves no scatter at all.  Otherwise, and always
+    with ``use_kernel``, it projects all ``N_src`` rows and gathers ``z``.
+
+    Inside ``layer{k}_aggregate`` the attention's parts are scoped as
+    ``edge_scores`` (e_u/e_v, LeakyReLU, mask), ``edge_softmax`` and
+    ``edge_gather_sum`` (the weighted sum, and the gather ``z[idx]`` where
+    the layer projects first)."""
+    n_dst = nbr_idx.shape[0]
+    H, dh = p["b"].shape
+    idx = jnp.maximum(nbr_idx, 0)
+
+    def project(x):
+        return jax.nn.relu(jnp.einsum("...d,dhe->...he", x, p["w"]) + p["b"])
+
+    if not use_kernel and h_src.shape[1] < H * dh:
+        with jax.named_scope(f"layer{layer}_update"):
+            z_e = project(h_src[idx])                      # [N_dst, f, H, dh]
+            z_v = project(h_src[:n_dst])                   # [N_dst, H, dh]
+        with jax.named_scope(f"layer{layer}_aggregate"):
+            with jax.named_scope("edge_scores"):
+                e_u = (z_e * p["a_u"]).sum(-1)             # [N_dst, f, H]
+                e_v = (z_v * p["a_v"]).sum(-1)             # [N_dst, H]
+            h = _attend(e_u, e_v, z_e, nbr_idx, valid)
+        return h.reshape(n_dst, -1)
+
     with jax.named_scope(f"layer{layer}_update"):
-        z = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h_src, p["w"]) + p["b"])
+        z = project(h_src)                                 # [N_src, H, dh]
     with jax.named_scope(f"layer{layer}_aggregate"):
         with jax.named_scope("edge_scores"):
             e_u = (z * p["a_u"]).sum(-1)                   # [N_src, H]
             e_v = (z * p["a_v"]).sum(-1)
-        n_dst = nbr_idx.shape[0]
         if use_kernel:
             from repro.kernels import ops as kops
             h = kops.gat_edge_aggregate(z, e_u, e_v, nbr_idx, valid)
         else:
-            idx = jnp.maximum(nbr_idx, 0)
             with jax.named_scope("edge_scores"):
-                mask = (nbr_idx >= 0) & valid[idx]         # [N_dst, f]
-                scores = jax.nn.leaky_relu(
-                    e_u[idx] + e_v[:n_dst, None, :], 0.2)  # [N_dst, f, H]
-                scores = jnp.where(mask[..., None], scores, -1e30)
-            with jax.named_scope("edge_softmax"):
-                alpha = jax.nn.softmax(scores, axis=1)
-                alpha = jnp.where(mask[..., None], alpha, 0.0)
+                e_u = e_u[idx]                             # [N_dst, f, H]
             with jax.named_scope("edge_gather_sum"):
-                h = jnp.einsum("nfh,nfhe->nhe", alpha,
-                               z[idx])                     # [N_dst, H, dh]
-        return h.reshape(n_dst, -1)
+                z_e = z[idx]                               # [N_dst, f, H, dh]
+            h = _attend(e_u, e_v[:n_dst], z_e, nbr_idx, valid)
+    return h.reshape(n_dst, -1)
+
+
+def _attend(e_u, e_v, z_e, nbr_idx, valid):
+    """Per-edge scores e_u [N_dst, f, H] and e_v [N_dst, H] -> the softmax
+    over each destination's valid slots, weighting z_e [N_dst, f, H, dh]
+    into [N_dst, H, dh]; a destination with no valid slot gets zeros."""
+    with jax.named_scope("edge_scores"):
+        mask = (nbr_idx >= 0) & valid[jnp.maximum(nbr_idx, 0)]  # [N_dst, f]
+        scores = jax.nn.leaky_relu(e_u + e_v[:, None, :], 0.2)
+        scores = jnp.where(mask[..., None], scores, -1e30)
+    with jax.named_scope("edge_softmax"):
+        alpha = jax.nn.softmax(scores, axis=1)
+        alpha = jnp.where(mask[..., None], alpha, 0.0)
+    with jax.named_scope("edge_gather_sum"):
+        return jnp.einsum("nfh,nfhe->nhe", alpha, z_e)
 
 
 def forward(params, h0, valid0, blocks, *, dropout: float = 0.0,
