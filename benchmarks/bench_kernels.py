@@ -1,7 +1,7 @@
-"""Serve/sample kernel microbenchmarks (the PR 9 raw-speed pass).
+"""Serve kernel microbenchmarks (the PR 9 raw-speed pass).
 
-Three row families, each kernel against the composed-jnp path it
-replaces:
+Two kernel row families, each against the composed-jnp path it
+replaces, plus the host fanout draw:
 
   * fused serve layer (``kernels/serve_fused.py``) vs the jit'd composed
     ``ref.serve_layer_ref`` — gather + masked mean + dense UPDATE in one
@@ -14,8 +14,7 @@ replaces:
   * batched HEC probe (``hec_search_batched``) vs N single
     ``hec_search_kernel`` dispatches — one grid over all fused exchange
     rounds.  SMOKE GATE: one batched call beats N singles.
-  * device fanout draw (``kernels/sample_draw.py``) vs the host numpy
-    ``_draw_neighbors`` loop, plus per-policy rows (uniform/labor/cv).
+  * the host fanout draw ``_draw_neighbors`` (Floyd's algorithm).
 
 All jitted paths take their operands as *arguments* — closing over
 concrete arrays lets XLA constant-fold the gather at trace time and the
@@ -143,9 +142,8 @@ def main(iters=8, smoke=False):
             f"SMOKE GATE: batched probe ({t_batched:.1f}us) not faster "
             f"than {rounds} single probes ({t_single:.1f}us)")
 
-    # -- device fanout draw ----------------------------------------------
+    # -- host fanout draw ------------------------------------------------
     from repro.graph import partition_graph, synthetic_graph
-    from repro.pipeline.vectorized_sampler import DeviceSampler
     nv = 2000 if smoke else 50_000
     g = synthetic_graph(num_vertices=nv, avg_degree=12, num_classes=4,
                         feat_dim=8, seed=3)
@@ -159,22 +157,12 @@ def main(iters=8, smoke=False):
                                 part.num_solid, fanout, host_rng),
         iters=iters)
     emit("sample_host_np", t_host, "")
-    draw_res = {"host_us": t_host}
-    for policy in ("uniform", "labor", "cv"):
-        dev = DeviceSampler(part, base_seed=0, policy=policy)
-        if policy == "cv":
-            dev.set_residency(rng.random(part.num_solid + part.num_halo)
-                              > 0.5)
-        t_dev = time_fn(lambda: dev.draw(0, 0, 0, cur, fanout), iters=iters)
-        emit(f"sample_device_{policy}", t_dev,
-             f"vs_host={t_host / t_dev:.2f}x")
-        draw_res[f"device_{policy}_us"] = t_dev
 
     result({"serve": serve_res,
             "probe": {"single_us": t_single, "batched_us": t_batched,
                       "rounds": rounds,
                       "speedup": t_single / t_batched},
-            "sampler": draw_res})
+            "sampler": {"host_us": t_host}})
 
 
 if __name__ == "__main__":

@@ -3,10 +3,9 @@
 Two measurements on the synthetic OGBN-like graph:
   * sampler-only throughput: reference per-row-loop ``sample_blocks`` vs
     the vectorized CSR sampler (acceptance bar: >=5x),
-  * end-to-end epoch time of ``DistTrainer.train_epochs``: legacy
-    synchronous path (reference sampler, no overlap) vs the pipeline's
-    synchronous fallback (vectorized, 0 workers) vs the full async pipeline
-    (prefetch workers + double-buffered staging).
+  * end-to-end epoch time of ``DistTrainer.train_epochs``: inline
+    sampling (0 workers) vs the async pipeline (one prefetch worker),
+    both with double-buffered staging.
 
 Emits the usual ``name,us_per_call,derived`` CSV rows plus one
 ``RESULT{...}`` JSON line with the raw numbers.
@@ -52,7 +51,7 @@ def bench_epoch(ps, epochs=2):
 
     mesh = jax.make_mesh((1,), ("data",))
 
-    def run(pipe_cfg, pipeline):
+    def run(pipe_cfg):
         cfg = small_gnn_config("graphsage", batch_size=512, feat_dim=32,
                                num_classes=16, fanouts=(5, 10),
                                hidden_size=64, pipeline=pipe_cfg)
@@ -61,31 +60,24 @@ def bench_epoch(ps, epochs=2):
         state = tr.init_state(jax.random.key(0))
         step_fn = tr.make_step(dd)
         # warmup epoch compiles the step and pre-touches caches
-        state, _ = tr.train_epochs(ps, dd, state, 1, step_fn=step_fn,
-                                   pipeline=pipeline)
+        state, _ = tr.train_epochs(ps, dd, state, 1, step_fn=step_fn)
         t0 = time.perf_counter()
-        state, hist = tr.train_epochs(ps, dd, state, epochs, step_fn=step_fn,
-                                      pipeline=pipeline)
+        state, hist = tr.train_epochs(ps, dd, state, epochs, step_fn=step_fn)
         return (time.perf_counter() - t0) / epochs, hist
 
-    sync_cfg = PipelineConfig(num_workers=0, double_buffer=False)
-    t_legacy, _ = run(sync_cfg, pipeline=None)          # reference sampler
-    t_sync, h_sync = run(sync_cfg, pipeline="auto")     # vectorized, inline
-    async_cfg = PipelineConfig(num_workers=1, prefetch_depth=1)
-    t_async, h_async = run(async_cfg, pipeline="auto")
+    t_sync, h_sync = run(PipelineConfig(num_workers=0))     # inline
+    t_async, h_async = run(PipelineConfig(num_workers=1, prefetch_depth=1))
 
     # worker count must not change the training trajectory (bit-identical)
     drift = max(abs(a["loss"] - b["loss"])
                 for a, b in zip(h_sync, h_async))
-    emit("pipeline_epoch_legacy_sync", t_legacy * 1e6, "")
-    emit("pipeline_epoch_vectorized_sync", t_sync * 1e6,
-         f"speedup={t_legacy/t_sync:.2f}x")
+    emit("pipeline_epoch_vectorized_sync", t_sync * 1e6, "")
     # NB on a host-only CPU backend sampling threads share cores with XLA,
     # so async ~= sync here; the overlap pays off when the device is real.
     emit("pipeline_epoch_async", t_async * 1e6,
-         f"speedup={t_legacy/t_async:.2f}x;loss_drift={drift:.1e}")
-    return {"epoch_legacy_us": t_legacy * 1e6, "epoch_sync_us": t_sync * 1e6,
-            "epoch_async_us": t_async * 1e6, "loss_drift": drift}
+         f"speedup={t_sync/t_async:.2f}x;loss_drift={drift:.1e}")
+    return {"epoch_sync_us": t_sync * 1e6, "epoch_async_us": t_async * 1e6,
+            "loss_drift": drift}
 
 
 def main(smoke=False):

@@ -14,7 +14,7 @@ Prints ``name,us_per_call,derived`` CSV lines.
   roofline                   dry-run roofline table (deliverable g)
   obs    bench_obs          tracing overhead gate (<10%) + TRACE_obs.json
   quality bench_quality     staleness sweep: epoch time vs accuracy vs audit err
-  kernels bench_kernels     fused serve / batched probe / device draw kernels
+  kernels bench_kernels     fused serve / batched probe kernels, host draw
   resilience bench_resilience ckpt save/restore, degraded serving, recovery
 
 ``--smoke`` runs every registered benchmark at tiny scale (a CI bit-rot

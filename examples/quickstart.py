@@ -56,8 +56,8 @@ def main():
     state = trainer.init_state(jax.random.key(0))
 
     # 4. train + evaluate — minibatches flow through the async pipeline
-    # (repro.pipeline: vectorized sampler + prefetch + staged transfers;
-    # cfg.pipeline tunes it, pipeline=None falls back to synchronous)
+    # (repro.pipeline: vectorized host sampler + prefetch + staged
+    # transfers; cfg.pipeline sets the prefetch workers and depth)
     t0 = time.perf_counter()
     state, hist = trainer.train_epochs(ps, dd, state, num_epochs=5,
                                        log_every=1)

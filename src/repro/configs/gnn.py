@@ -3,6 +3,9 @@
 Mirrors Table 2 (GraphSAGE/GAT on OGBN datasets) and §4.4 HEC settings:
 cs=1M entries/layer, nc=2000, ls=2, d=1, minibatch 1000, fan-out 5,10,15.
 Scaled-down presets are provided for CPU-sized synthetic graphs.
+``PipelineConfig`` says only how the one minibatch source (the host
+sampler behind ``repro.pipeline.MinibatchPipeline``) overlaps the device
+step: prefetch worker count and depth.
 """
 from __future__ import annotations
 
@@ -44,51 +47,15 @@ class HECConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class SamplerConfig:
-    """Fanout-draw policy and placement (host numpy vs on-device kernel).
-
-    ``device_draw=False`` (default) keeps the host vectorized sampler —
-    byte-identical to every prior release and the fallback for host-only
-    backends.  ``device_draw=True`` moves the per-layer neighbor draw
-    onto the device (``kernels/sample_draw.py``): deterministic per
-    (base_seed, epoch, step, rank, layer) via ``jax.random`` fold_in
-    chaining, hence bit-reproducible for any prefetch worker count.
-
-    Policies (device draw only — the host loop stays uniform):
-      uniform  iid neighbor sampling (NS; the paper's sampler)
-      labor    LABOR-style correlated draw: one shared hash key per
-               *vertex*, so overlapping fanouts select the same
-               neighbors and the minibatch frontier shrinks
-      cv       control-variate sampling (arxiv 1710.10568): LABOR keys
-               divided by ``1 + cv_boost * resident``, preferring
-               vertices whose historical activations sit in the HEC —
-               the trainer refreshes residency from the live cache tags
-               each epoch
-    """
-    policy: str = "uniform"         # uniform | labor | cv
-    device_draw: bool = False       # on-device kernel draw (host np default)
-    cv_boost: float = 4.0           # cv: weight boost for HEC-resident rows
-    use_kernel: bool = True         # Pallas keys kernel (False = jnp ref)
-
-    def __post_init__(self):
-        if self.policy not in ("uniform", "labor", "cv"):
-            raise ValueError(f"policy must be uniform|labor|cv, "
-                             f"got {self.policy!r}")
-        if self.policy != "uniform" and not self.device_draw:
-            raise ValueError(
-                f"policy={self.policy!r} needs device_draw=True "
-                f"(the host fallback draw is uniform-only)")
-
-
-@dataclasses.dataclass(frozen=True)
 class PipelineConfig:
     """Asynchronous minibatch pipeline (repro.pipeline) parameters.
 
     The paper's §3.3 sampler is synchronous thread-parallel; our analogue
-    vectorizes the CSR fanout draw and overlaps minibatch preparation with
-    the device step (DistDGL/MassiveGNN-style prefetching).  Results are
-    bit-identical for any ``num_workers`` — each step owns an RNG stream —
-    so worker count is purely a throughput knob.
+    vectorizes the CSR fanout draw on the host and overlaps minibatch
+    preparation with the device step (DistDGL/MassiveGNN-style
+    prefetching).  Results are bit-identical for any ``num_workers`` —
+    each step owns an RNG stream — so worker count is purely a throughput
+    knob.
 
     Defaults are deliberately conservative (one worker, one batch ahead):
     on an accelerator that fully hides sampling behind the device step,
@@ -96,13 +63,8 @@ class PipelineConfig:
     compute share cores — it stays neutral.  Raise ``num_workers`` /
     ``prefetch_depth`` when the device step is long relative to sampling.
     """
-    enabled: bool = True            # default training path uses the pipeline
     num_workers: int = 1            # 0 = synchronous inline sampling
     prefetch_depth: int = 1         # minibatches sampled ahead of the step
-    double_buffer: bool = True      # overlap device_put(k+1) with step k
-    vectorized: bool = True         # vectorized CSR sampler (vs reference)
-    sampler: SamplerConfig = dataclasses.field(
-        default_factory=SamplerConfig)
 
     def __post_init__(self):
         if self.num_workers < 0:

@@ -15,6 +15,10 @@ Block layout for an L-layer GNN (seeds at layer L-1):
 
 Halo vertices are never expanded (their embeddings come from the HEC), so
 they appear only as leaves.
+
+``sample_blocks`` is the plain per-row reference: tests hold the program's
+one draw, ``repro.pipeline``'s vectorized sampler, to it; the program's
+path does not call it.
 """
 from __future__ import annotations
 

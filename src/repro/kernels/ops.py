@@ -9,14 +9,12 @@ from repro.kernels.gat_edge import gat_edge
 from repro.kernels.hec_search import (hec_probe, hec_search_batched,
                                       hec_search_kernel)
 from repro.kernels.sage_agg import sage_agg
-from repro.kernels.sample_draw import draw_neighbors_device, sample_keys_kernel
 from repro.kernels.serve_fused import fused_serve_layer
 from repro.kernels.update_fused import fused_update
 
 __all__ = ["fused_update", "sage_agg", "gat_edge", "gat_edge_aggregate",
            "hec_search_kernel", "hec_search_batched", "hec_probe",
-           "fused_serve_layer", "sample_keys_kernel",
-           "draw_neighbors_device"]
+           "fused_serve_layer"]
 
 
 def gat_edge_aggregate(z, e_u, e_v, nbr_idx, src_valid):

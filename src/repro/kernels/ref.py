@@ -10,8 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.gnn.common import (_MIX1, _MIX2, gather_neighbors,
-                                     hash_uniform, masked_mean)
+from repro.models.gnn.common import (gather_neighbors, hash_uniform,
+                                     masked_mean)
 
 
 def fused_update_ref(agg, self_h, wn, ws, b, *, relu=True, dropout=0.0,
@@ -60,49 +60,6 @@ def serve_layer_ref(p, h_src, nbr_idx, src_valid, self_h=None, *, relu=True):
         self_h = h_src[: nbr_idx.shape[0]]
     return sage_lib.update(p, agg, self_h, relu=relu, dropout=0.0,
                            seed=jnp.uint32(0))
-
-
-def _hash_u01(a, b, seed):
-    """The repo-wide u32 mix hash → uniform [0,1) f32, elementwise 2-D.
-
-    Same arithmetic as ``common.hash_uniform`` but over arbitrary 2-D
-    uint32 operands so vertex-keyed (LABOR) policies can reuse it.
-    """
-    h = (a.astype(jnp.uint32) * _MIX1) ^ (b.astype(jnp.uint32) * _MIX2)
-    h = h ^ seed.astype(jnp.uint32)
-    h = h ^ (h >> jnp.uint32(15))
-    h = h * _MIX1
-    h = h ^ (h >> jnp.uint32(13))
-    return (h >> jnp.uint32(8)).astype(jnp.float32) / jnp.float32(1 << 24)
-
-
-def sample_keys_ref(seed, nbr_vid, weights=None, *, policy="uniform"):
-    """Selection keys for the fanout draw; the f *smallest* keys win.
-
-    nbr_vid [n, W] candidate neighbor vids, -1 = out-of-row padding.
-    Returns [n, W] float32 keys, +inf on padded slots.
-
-    uniform  key = hash(row, slot)    — iid per slot (classic NS draw)
-    labor    key = hash(vid)          — one shared key per *vertex*, so
-             overlapping fanouts pick the same neighbors (LABOR-style
-             variance-zero correlated draw; marginal prob still f/deg)
-    cv       labor key / weight[slot] — weights ≥ 1 boost inclusion of
-             vertices with HEC-resident historical activations
-             (control-variate sampling, arxiv 1710.10568)
-    """
-    n, w = nbr_vid.shape
-    rows = jax.lax.broadcasted_iota(jnp.uint32, (n, w), 0)
-    cols = jax.lax.broadcasted_iota(jnp.uint32, (n, w), 1)
-    if policy == "uniform":
-        keys = _hash_u01(rows, cols, seed)
-    elif policy in ("labor", "cv"):
-        vid = jnp.maximum(nbr_vid, 0).astype(jnp.uint32)
-        keys = _hash_u01(vid, jnp.zeros_like(vid), seed)
-        if policy == "cv":
-            keys = keys / jnp.maximum(weights, 1e-6).astype(jnp.float32)
-    else:
-        raise ValueError(f"unknown sample policy: {policy!r}")
-    return jnp.where(nbr_vid >= 0, keys, jnp.inf)
 
 
 def gat_edge_ref(z, e_u, e_v, nbr_idx, src_valid):

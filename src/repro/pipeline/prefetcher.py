@@ -25,10 +25,8 @@ import numpy as np
 from repro import obs
 from repro.configs.gnn import GNNConfig
 from repro.graph.partition import PartitionSet
-from repro.graph.sampling import (epoch_minibatches, pad_schedule,
-                                  sample_blocks)
-from repro.pipeline.vectorized_sampler import (DeviceSampler,
-                                               sample_blocks_vectorized,
+from repro.graph.sampling import epoch_minibatches, pad_schedule
+from repro.pipeline.vectorized_sampler import (sample_blocks_vectorized,
                                                stack_ranks)
 
 # domain-separation tags so shuffle and sampling streams never collide
@@ -61,22 +59,6 @@ class SamplingPlan:
         return np.random.default_rng(
             [self.base_seed, epoch, step, _SAMPLE_TAG])
 
-    def device_samplers(self) -> List[DeviceSampler]:
-        """Lazy per-rank :class:`DeviceSampler`s (``device_draw`` only)."""
-        if getattr(self, "_dev_samplers", None) is None:
-            s = self.cfg.pipeline.sampler
-            self._dev_samplers = [
-                DeviceSampler(p, base_seed=self.base_seed, rank=r,
-                              policy=s.policy, cv_boost=s.cv_boost,
-                              use_kernel=s.use_kernel)
-                for r, p in enumerate(self.ps.parts)]
-        return self._dev_samplers
-
-    def set_cv_residency(self, masks: Sequence[np.ndarray]) -> None:
-        """Install per-rank HEC residency (bool over VID_p) for cv draws."""
-        for dev, m in zip(self.device_samplers(), masks):
-            dev.set_residency(m)
-
     def sample_host(self, epoch: int, step: int,
                     seed_lists: Sequence[np.ndarray]) -> dict:
         """One synchronized [R, ...] host minibatch for ``(epoch, step)``."""
@@ -86,26 +68,13 @@ class SamplingPlan:
             # fault — the retry of the same (epoch, step) then succeeds
             self.injector.prefetch_crash(epoch, step)
         rng = self.step_rng(epoch, step)
-        sampler = (sample_blocks_vectorized if cfg.pipeline.vectorized
-                   else sample_blocks)
-        # on-device draw: per-rank draw_fn closures over (epoch, step);
-        # determinism is carried by the fold-in seed chain, not `rng`
-        use_dev = (cfg.pipeline.sampler.device_draw
-                   and cfg.pipeline.vectorized)
-        devs = self.device_samplers() if use_dev else None
         # the two host phases of minibatch preparation, timed separately:
         # CSR fanout sampling vs the [R, ...] stacking/padding host prep
         # (spans run on whichever prefetch worker executes the step)
         with obs.span("sample", epoch=epoch, step=step):
-            mbs = []
-            for r in range(self.ps.num_parts):
-                kw = {}
-                if use_dev:
-                    kw["draw_fn"] = (
-                        lambda k, cur, f, allow, _d=devs[r]:
-                        _d.draw(epoch, step, k, cur, f, allow))
-                mbs.append(sampler(self.ps.parts[r], seed_lists[r],
-                                   cfg.fanouts, rng, cfg.batch_size, **kw))
+            mbs = [sample_blocks_vectorized(part, seed_lists[r], cfg.fanouts,
+                                            rng, cfg.batch_size)
+                   for r, part in enumerate(self.ps.parts)]
         with obs.span("host_prep", epoch=epoch, step=step):
             return stack_ranks(mbs)
 

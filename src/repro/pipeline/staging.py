@@ -1,17 +1,17 @@
 """Double-buffered host->device staging + the `MinibatchPipeline` iterator.
 
-The full asynchronous minibatch path (paper §3.3 sampler + §3.4 overlap,
-DistDGL/MassiveGNN-style prefetching):
+The one minibatch source for training and evaluation (paper §3.3 sampler
++ §3.4 overlap, DistDGL/MassiveGNN-style prefetching):
 
-    vectorized CSR sampler --> prefetch thread pool (deterministic per-step
-    RNG streams, bounded depth) --> double-buffered ``jax.device_put`` -->
-    compiled shard_map train step
+    vectorized CSR sampler (host draw) --> prefetch thread pool
+    (deterministic per-step RNG streams, bounded depth) -->
+    double-buffered ``jax.device_put`` --> compiled shard_map train step
 
 Double buffering exploits jax's asynchronous dispatch: while the device
 executes step ``k``, the host has already issued the transfer for step
 ``k+1``, so sampling *and* H2D copies hide behind compute.  With
-``num_workers=0`` and ``double_buffer=False`` the pipeline degrades to a
-fully synchronous reference path that produces bit-identical batches.
+``num_workers=0`` sampling runs inline on the loop thread and produces
+bit-identical batches.
 """
 from __future__ import annotations
 
@@ -28,7 +28,7 @@ from repro.pipeline.prefetcher import SamplingPlan, prefetch
 _EVAL_EPOCH_TAG = 1 << 20   # eval streams live far away from training epochs
 
 
-def device_stage(host_batches: Iterator[dict], double_buffer: bool = True,
+def device_stage(host_batches: Iterator[dict],
                  sharding=None) -> Iterator[dict]:
     """Map host minibatches to device, keeping one transfer in flight.
 
@@ -48,10 +48,6 @@ def device_stage(host_batches: Iterator[dict], double_buffer: bool = True,
         with obs.span("stage"):
             return raw_put(host)
 
-    if not double_buffer:
-        for host in host_batches:
-            yield put(host)
-        return
     staged = None
     for host in host_batches:
         nxt = put(host)
@@ -85,20 +81,13 @@ class MinibatchPipeline:
     def num_ranks(self) -> int:
         return self.plan.ps.num_parts
 
-    def set_cv_residency(self, masks: Sequence[np.ndarray]) -> None:
-        """Refresh the cv sampler's per-rank HEC residency (see
-        ``SamplingPlan.set_cv_residency``); the trainer calls this at
-        each epoch boundary when ``sampler.policy == "cv"``."""
-        self.plan.set_cv_residency(masks)
-
     def batches(self, schedule: List[Sequence[np.ndarray]],
                 epoch: int) -> Iterator[dict]:
         """Pipeline an explicit ``schedule[step][rank]`` seed schedule."""
         make = lambda step: self.plan.sample_host(epoch, step, schedule[step])
         host_iter = prefetch(make, len(schedule), self.pcfg.num_workers,
                              self.pcfg.prefetch_depth)
-        return device_stage(host_iter, self.pcfg.double_buffer,
-                            sharding=self.sharding)
+        return device_stage(host_iter, sharding=self.sharding)
 
     def epoch_batches(self, epoch: int) -> Iterator[dict]:
         """Device minibatches for one training epoch (shuffled, padded)."""

@@ -100,8 +100,7 @@ def sample_blocks_vectorized(part: Partition, seeds_p: np.ndarray,
                              rng: np.random.Generator,
                              batch_size: int,
                              expandable: Optional[Sequence[np.ndarray]]
-                             = None,
-                             draw_fn=None) -> MinibatchBlocks:
+                             = None) -> MinibatchBlocks:
     """Drop-in replacement for ``sample_blocks`` (same contract, >5x faster).
 
     The RNG consumption pattern differs from the reference sampler, so
@@ -115,12 +114,6 @@ def sample_blocks_vectorized(part: Partition, seeds_p: np.ndarray,
     its layer-``k`` embedding is expected from a cache (serving) or the HEC
     (training halos), so its subtree is never sampled.  Entry 0 is unused
     (layer 0 is never expanded).
-
-    ``draw_fn`` (optional) substitutes the per-layer fanout draw:
-    ``draw_fn(k, cur, f, allow) -> [len(cur), f]`` neighbor VID_p matrix
-    (-1 pad), same contract as ``_draw_neighbors``.  Used by
-    :class:`DeviceSampler` to run the draw on-device; ``rng`` is then
-    unused for the draw itself.
     """
     fanouts = list(fanouts)
     L = len(fanouts)
@@ -150,11 +143,8 @@ def sample_blocks_vectorized(part: Partition, seeds_p: np.ndarray,
             # halos or padding, which never expand regardless of `allow`
             m = expandable[k + 1]
             allow = m[np.where((cur >= 0) & (cur < len(m)), cur, 0)]
-        if draw_fn is not None:
-            nbrs = draw_fn(k, cur, f, allow)
-        else:
-            nbrs = _draw_neighbors(part.indptr, part.indices, cur, S, f,
-                                   rng, allow=allow)
+        nbrs = _draw_neighbors(part.indptr, part.indices, cur, S, f, rng,
+                               allow=allow)
 
         # finer node list: dst prefix + sorted unique new neighbors
         flat = nbrs.ravel()
@@ -187,74 +177,6 @@ def sample_blocks_vectorized(part: Partition, seeds_p: np.ndarray,
     return MinibatchBlocks(layer_nodes=layer_nodes, node_mask=node_mask,
                            nbr_idx=nbr_idx, seeds=seeds, seed_mask=seed_mask,
                            labels=labels)
-
-
-class DeviceSampler:
-    """On-device fanout draw bound to one partition (kernels/sample_draw).
-
-    Replaces the host ``np.random`` draw loop when
-    ``SamplerConfig.device_draw`` is on: the partition's solid CSR lives
-    on the device once, and each ``draw`` call is one jitted kernel
-    dispatch.  Draws are *stateless* — the selection seed is derived from
-    (base_seed, epoch, step, rank, layer) by ``jax.random`` fold_in
-    chaining — so results are bit-reproducible for any prefetch worker
-    count and safe to issue from multiple prefetcher threads.
-
-    ``set_residency`` installs the control-variate weight table (policy
-    "cv"): per-VID_p weights ``1 + cv_boost * resident`` derived from the
-    trainer's live HEC tags, refreshed once per epoch.
-    """
-
-    def __init__(self, part: Partition, base_seed: int = 0, rank: int = 0,
-                 policy: str = "uniform", cv_boost: float = 4.0,
-                 use_kernel: bool = True):
-        import jax.numpy as jnp     # lazy: module stays importable w/o jax
-        self.part = part
-        self.base_seed = int(base_seed)
-        self.rank = int(rank)
-        self.policy = policy
-        self.cv_boost = float(cv_boost)
-        self.use_kernel = bool(use_kernel)
-        self.num_solid = part.num_solid
-        deg = part.indptr[1:] - part.indptr[:-1]
-        self.width = max(int(deg.max()) if part.num_solid else 0, 1)
-        self._indptr = jnp.asarray(part.indptr.astype(np.int32))
-        self._indices = jnp.asarray(part.indices.astype(np.int32))
-        n_vids = part.num_solid + part.num_halo
-        self._wtab = jnp.ones((max(n_vids, 1),), jnp.float32)
-
-    def set_residency(self, resident: np.ndarray) -> None:
-        """resident: bool [num_solid + num_halo] over VID_p — vertices
-        with a live HEC line; cv draws prefer them by ``1 + cv_boost``."""
-        import jax.numpy as jnp
-        w = 1.0 + self.cv_boost * np.asarray(resident, np.float32)
-        self._wtab = jnp.asarray(w.reshape(-1))
-
-    def _seed(self, epoch: int, step: int, layer: int):
-        import jax
-        import jax.numpy as jnp
-        key = jax.random.key(self.base_seed)
-        for x in (epoch, step, self.rank, layer):
-            key = jax.random.fold_in(key, x)
-        return jax.random.bits(key, (), jnp.uint32)
-
-    def draw(self, epoch: int, step: int, layer: int, cur: np.ndarray,
-             f: int, allow: Optional[np.ndarray] = None) -> np.ndarray:
-        """Device analogue of ``_draw_neighbors`` — [len(cur), f] VID_p."""
-        import jax.numpy as jnp
-        from repro import obs
-        from repro.kernels.sample_draw import draw_neighbors_device
-        allow_j = None if allow is None else jnp.asarray(allow)
-        with obs.span("kernel_sample_draw", layer=layer,
-                      policy=self.policy):
-            out = draw_neighbors_device(
-                self._indptr, self._indices, self._wtab,
-                jnp.asarray(cur.astype(np.int32)),
-                self._seed(epoch, step, layer), allow_j,
-                f=int(f), num_solid=int(self.num_solid),
-                width=self.width, policy=self.policy,
-                use_kernel=self.use_kernel)
-        return np.asarray(out).astype(np.int64)
 
 
 def _segment_perms(n_seg: int, caps: Sequence[int]) -> List[np.ndarray]:
@@ -332,9 +254,8 @@ def concat_blocks(mbs: Sequence[MinibatchBlocks]) -> MinibatchBlocks:
 def stack_ranks(mbs: Sequence[MinibatchBlocks]) -> Dict:
     """Stack per-rank blocks into the host-side [R, ...] minibatch layout.
 
-    Same structure/dtypes as ``repro.train.gnn_trainer.sample_step`` but kept
-    as numpy so prefetch workers never touch jax; ``staging`` owns the
-    host->device transfer.
+    Kept as numpy so prefetch workers never touch jax; ``staging`` owns
+    the host->device transfer.
     """
     L = mbs[0].num_layers
     return {

@@ -3,12 +3,8 @@
 LM side: a deterministic, shardable synthetic token stream (Markov bigram
 mixture — learnable, used by examples/train_lm.py and the smoke tests) plus
 a host-side prefetching iterator that yields device-ready global batches
-sharded over ("pod","data").
-
-GNN side: the epoch iterator that pairs per-rank seed batches with the
-synchronized sampler (repro.graph.sampling) — the paper's "synchronous
-minibatch creation" loop, factored out of the trainer for reuse by
-benchmarks and examples.
+sharded over ("pod","data").  GNN minibatches come from
+``repro.pipeline.MinibatchPipeline``.
 """
 from __future__ import annotations
 
@@ -97,21 +93,3 @@ def shard_batch(batch: dict, mesh, batch_axes: Optional[dict] = None):
             x, NamedSharding(mesh, axes_to_pspec(axes, x.shape, mesh)))
 
     return {k: place(k, v) for k, v in batch.items()}
-
-
-def gnn_epoch_iterator(ps, cfg, rng: np.random.Generator):
-    """Synchronized per-rank minibatches for one epoch (paper Alg. 2 line 4:
-    CreateMinibatches). Ranks with fewer batches contribute empty (fully
-    masked) batches — no seed is trained twice; the load imbalance is
-    reported, not hidden (paper §4.4)."""
-    from repro.graph.sampling import epoch_minibatches, pad_schedule
-    from repro.train.gnn_trainer import sample_step
-
-    per_rank = [epoch_minibatches(ps.parts[r], cfg.batch_size, rng)
-                for r in range(ps.num_parts)]
-    schedule = pad_schedule(per_rank)
-    M = len(schedule)
-    imbalance = (M - min(len(b) for b in per_rank)) / max(M, 1)
-    for seeds in schedule:
-        yield sample_step(ps, cfg, seeds, rng), {"imbalance": imbalance,
-                                                 "minibatches": M}

@@ -23,10 +23,9 @@ Modes:
          blocking request/response all_to_all pair every iteration
   drop — LLCG-like: cut edges ignored (halos invalid everywhere)
 
-Minibatches flow through ``repro.pipeline`` by default (vectorized CSR
-sampler -> background prefetch -> double-buffered staging, paper §3.3/§3.4
-overlap); ``train_epochs(..., pipeline=None)`` selects the legacy
-synchronous reference path.
+Minibatches have one source, ``repro.pipeline.MinibatchPipeline``
+(vectorized host CSR sampler -> background prefetch -> double-buffered
+staging, paper §3.3/§3.4 overlap), for training and evaluation alike.
 """
 from __future__ import annotations
 
@@ -48,9 +47,7 @@ from repro.comm.engine import HaloExchangeEngine
 from repro.comm.plan import _pad_stack, build_exchange_plan
 from repro.configs.gnn import GNNConfig
 from repro.graph.partition import PartitionSet
-from repro.graph.sampling import sample_blocks
 from repro.pipeline.staging import MinibatchPipeline
-from repro.pipeline.vectorized_sampler import stack_ranks
 from repro.resilience.inject import CODE_NAN_STEP
 from repro.models.gnn import gat as gat_lib
 from repro.models.gnn import graphsage as sage_lib
@@ -85,18 +82,6 @@ def build_dist_data(ps: PartitionSet, cfg: GNNConfig, mesh=None) -> dict:
                              for p in ps.parts], -1),
     }
     return {**jax.device_put(host, sharding), **plan_tables}
-
-
-def sample_step(ps: PartitionSet, cfg: GNNConfig, seed_lists, rng) -> dict:
-    """Sample one synchronized minibatch per rank -> stacked device arrays.
-
-    Legacy synchronous path (reference sampler); the batch layout contract
-    is owned by ``repro.pipeline.vectorized_sampler.stack_ranks``.
-    """
-    R = ps.num_parts
-    mbs = [sample_blocks(ps.parts[r], seed_lists[r], cfg.fanouts, rng,
-                         cfg.batch_size) for r in range(R)]
-    return jax.tree_util.tree_map(jnp.asarray, stack_ranks(mbs))
 
 
 def _epoch_mean(ep_metrics):
@@ -495,12 +480,9 @@ class DistTrainer:
                 metrics)
 
     # -- public API ----------------------------------------------------------
-    def _resolve_pipeline(self, ps, seed0, pipeline):
-        """"auto" -> MinibatchPipeline iff cfg.pipeline.enabled; else as-is."""
-        if pipeline != "auto":
-            return pipeline
-        if not self.cfg.pipeline.enabled:
-            return None
+    def _pipeline(self, ps, seed0):
+        """The minibatch source: the host sampler behind prefetch and
+        double-buffered staging, seeded by ``seed0``."""
         inj = getattr(self.resilience, "injector", None) \
             if self.resilience is not None else None
         return MinibatchPipeline(ps, self.cfg, base_seed=seed0,
@@ -547,18 +529,10 @@ class DistTrainer:
                        donate_argnums=(1, 2, 3, 4) if donate else ())
 
     def train_epochs(self, ps, dist_data, state, num_epochs, seed0=0,
-                     step_fn=None, log_every=0, pipeline="auto",
-                     start_epoch=0):
+                     step_fn=None, log_every=0, start_epoch=0):
         """Train for ``num_epochs`` (epochs ``start_epoch`` onward).
 
-        ``pipeline`` selects the minibatch source:
-          "auto"              — a ``MinibatchPipeline`` when the config's
-                                ``cfg.pipeline.enabled`` (the default path:
-                                vectorized sampler + background prefetch +
-                                double-buffered staging), else synchronous;
-          a MinibatchPipeline — used as given;
-          None                — legacy synchronous per-step sampling
-                                (reference ``sample_blocks``, no overlap).
+        Minibatches come from a ``MinibatchPipeline`` seeded by ``seed0``.
         Ranks with fewer minibatches than the epoch maximum contribute empty
         (fully masked) batches; metrics count only real examples.
 
@@ -568,8 +542,7 @@ class DistTrainer:
         replays the exact sampler streams of the uninterrupted run.
         """
         cfg = self.cfg
-        pipeline = self._resolve_pipeline(ps, seed0, pipeline)
-        rng = np.random.default_rng(seed0)
+        pipeline = self._pipeline(ps, seed0)
         step_fn = step_fn or self.make_step(dist_data)
         history = []
         step_idx = int(state["step"])
@@ -592,7 +565,6 @@ class DistTrainer:
             else contextlib.nullcontext()
         rz = self.resilience
         armed = rz is not None and getattr(rz, "step_armed", False)
-        s_policy = cfg.pipeline.sampler.policy
         with guard:
             for ep in range(start_epoch, start_epoch + num_epochs):
                 ph0, wall0 = phase_at(), time.perf_counter()
@@ -600,22 +572,7 @@ class DistTrainer:
                 # the first minibatch in hand), then per step the step span
                 # and the batch_wait for the next minibatch, then epoch_end
                 with obs.span("epoch_fill", epoch=ep):
-                    if (pipeline is not None and s_policy == "cv"
-                            and cfg.pipeline.sampler.device_draw):
-                        # control-variate sampling: refresh the per-rank
-                        # HEC residency the cv draw weights read — vertices
-                        # with a live historical activation get preferred
-                        # at sample time (arxiv 1710.10568), and the set
-                        # tracked here is exactly what the epoch's lookups
-                        # can hit
-                        pipeline.set_cv_residency(
-                            self._cv_residency(ps, state))
-                    if pipeline is not None:
-                        mb_iter = pipeline.epoch_batches(ep)
-                    else:
-                        from repro.train.data import gnn_epoch_iterator
-                        mb_iter = (mb for mb, _ in
-                                   gnn_epoch_iterator(ps, cfg, rng))
+                    mb_iter = pipeline.epoch_batches(ep)
                     mb = next(mb_iter, None)
                 ep_metrics = []
                 t_step_ep = 0.0
@@ -659,14 +616,9 @@ class DistTrainer:
                         mb = next(mb_iter, None)
                 with obs.span("epoch_end", epoch=ep):
                     mean = _epoch_mean(ep_metrics)
-                    # annotate which fanout-draw policy produced the epoch so
-                    # downstream consumers (history rows, the labeled counter)
-                    # can attribute convergence/perf deltas to the sampler
-                    mean["sampler_policy"] = s_policy
                     wall = time.perf_counter() - wall0
                     if reg.enabled:
-                        reg.counter("train_epochs_total",
-                                    sampler_policy=s_policy).inc()
+                        reg.counter("train_epochs_total").inc()
                         # per-epoch phase seconds (sample/host_prep run on the
                         # prefetch workers, so an epoch is credited with
                         # whatever preparation completed during it — exact at
@@ -727,25 +679,6 @@ class DistTrainer:
             rz.finalize(health)
         return state, history
 
-    def _cv_residency(self, ps, state):
-        """Per-rank bool masks over VID_p: vertices with a live line in
-        ANY layer of that rank's training HEC (tags hold VID_o).  This is
-        the control-variate sampler's weight source — one host read of
-        the tag tensors per epoch, no device-step change."""
-        R = self.num_ranks
-        V = sum(p.num_solid for p in ps.parts)
-        res_o = np.zeros((R, V), bool)
-        for st in state["hec"]:
-            tags = np.asarray(st.tags)            # [R, nsets, ways] VID_o
-            for r in range(R):
-                t = tags[r][tags[r] >= 0]
-                res_o[r, t[t < V]] = True
-        masks = []
-        for r, p in enumerate(ps.parts):
-            vid_o = np.clip(p.vid_p_to_o(), 0, V - 1)
-            masks.append(res_o[r, vid_o])
-        return masks
-
     def audit(self, ps, dist_data, state, epoch: int = 0):
         """Online exactness audit: sample cached lines from each training
         HEC (and fresh hot-tier replicas), recompute their exact ``h^l``
@@ -797,28 +730,16 @@ class DistTrainer:
                            source="train")
 
     def evaluate(self, ps, dist_data, state, num_batches=8, seed0=123,
-                 step_fn=None, pipeline="auto"):
+                 step_fn=None):
         """Test accuracy via sampled minibatches over test vertices."""
         cfg = self.cfg
-        rng = np.random.default_rng(seed0)
         R = self.num_ranks
         if step_fn is None:
             ecfg = dataclasses.replace(cfg, dropout=0.0)
             step_fn = dataclasses.replace(self, cfg=ecfg).make_step(
                 donate=False)
-        pipeline = self._resolve_pipeline(ps, seed0, pipeline)
-        if pipeline is not None:
-            mb_iter = pipeline.eval_batches(num_batches, seed=seed0)
-        else:
-            def _legacy():
-                for _ in range(num_batches):
-                    seeds = []
-                    for r in range(R):
-                        test = np.flatnonzero(ps.parts[r].test_mask)
-                        rng.shuffle(test)
-                        seeds.append(test[:cfg.batch_size])
-                    yield sample_step(ps, cfg, seeds, rng)
-            mb_iter = _legacy()
+        mb_iter = self._pipeline(ps, seed0).eval_batches(num_batches,
+                                                         seed=seed0)
         # a step-armed trainer's compiled step takes the fault-code input;
         # evaluation always runs clean (all-zero codes — same bits)
         fargs = ((jnp.zeros((R,), jnp.int32),)

@@ -1,8 +1,8 @@
-"""Data pipeline tests: determinism, prefetch, GNN epoch iterator."""
+"""Data pipeline tests: determinism and prefetch."""
 import numpy as np
 import pytest
 
-from repro.train.data import Prefetcher, TokenStream, gnn_epoch_iterator
+from repro.train.data import Prefetcher, TokenStream
 
 
 def test_token_stream_deterministic():
@@ -34,21 +34,3 @@ def test_prefetcher_preserves_order():
     it = iter([{"x": np.array([i])} for i in range(10)])
     got = [int(b["x"][0]) for b in Prefetcher(it, depth=3)]
     assert got == list(range(10))
-
-
-def test_gnn_epoch_iterator_covers_epoch():
-    from repro.configs.gnn import small_gnn_config
-    from repro.graph import partition_graph, synthetic_graph
-    g = synthetic_graph(num_vertices=1200, avg_degree=6, num_classes=4,
-                        feat_dim=8, seed=2)
-    ps = partition_graph(g, 2, seed=0)
-    cfg = small_gnn_config("graphsage", batch_size=32, feat_dim=8,
-                           num_classes=4)
-    rng = np.random.default_rng(0)
-    n_steps = 0
-    for mb, info in gnn_epoch_iterator(ps, cfg, rng):
-        assert mb["seeds"].shape[0] == 2          # one per rank
-        assert 0.0 <= info["imbalance"] <= 1.0
-        n_steps += 1
-    want = max(int(np.ceil(p.train_mask.sum() / 32)) for p in ps.parts)
-    assert n_steps == want

@@ -229,87 +229,3 @@ def test_hec_probe_matches_hec_lookup():
                                       np.asarray(hit_l))
         np.testing.assert_array_equal(np.asarray(emb_p[i]),
                                       np.asarray(emb_l))
-
-
-@pytest.mark.parametrize("policy", ["uniform", "labor", "cv"])
-def test_sample_keys_kernel_matches_ref(policy):
-    """Pallas selection-key kernel bit-matches the jnp oracle for every
-    policy, including +inf on padded (-1) slots."""
-    rng = np.random.default_rng(3)
-    nbr = jnp.asarray(rng.integers(-1, 500, (37, 13)), jnp.int32)
-    w = jnp.asarray(1.0 + 4.0 * rng.random((37, 13)), jnp.float32)
-    seed = jnp.uint32(0xABCD1234)
-    out = ops.sample_keys_kernel(seed, nbr, w, policy=policy)
-    exp = ref.sample_keys_ref(seed, nbr, w, policy=policy)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
-    assert bool(jnp.isinf(out[nbr < 0]).all())
-
-
-def _tiny_csr():
-    # 6 solid vertices; degrees 2,8,0,1,3,5 over vids 0..13 (8 halos)
-    indptr = np.array([0, 2, 10, 10, 11, 14, 19], np.int64)
-    indices = np.array([7, 1, 0, 2, 3, 4, 5, 6, 8, 9, 13,
-                        2, 10, 11, 1, 3, 6, 12, 13], np.int64)
-    return indptr, indices
-
-
-@pytest.mark.parametrize("policy", ["uniform", "labor", "cv"])
-def test_draw_neighbors_device_edges(policy):
-    """Take-all rows stay in CSR order; halo/pad/deg-0 rows are all -1;
-    sampled rows draw exactly f in-row neighbors without replacement."""
-    from repro.kernels.sample_draw import draw_neighbors_device
-    indptr, indices = _tiny_csr()
-    f, num_solid = 4, 6
-    wtab = jnp.ones((14,), jnp.float32)
-    cur = jnp.asarray([0, 1, 2, 3, 4, 5, -1, 9], jnp.int32)  # 9 = halo
-    out = np.asarray(draw_neighbors_device(
-        jnp.asarray(indptr, jnp.int32), jnp.asarray(indices, jnp.int32),
-        wtab, cur, jnp.uint32(42), None, f=f, num_solid=num_solid,
-        width=8, policy=policy))
-    # deg<=f rows keep every neighbor, CSR order, left-packed
-    np.testing.assert_array_equal(out[0], [7, 1, -1, -1])
-    np.testing.assert_array_equal(out[2], [-1] * f)          # deg 0
-    np.testing.assert_array_equal(out[3], [13, -1, -1, -1])
-    np.testing.assert_array_equal(out[4], [2, 10, 11, -1])
-    np.testing.assert_array_equal(out[6], [-1] * f)          # cur = -1
-    np.testing.assert_array_equal(out[7], [-1] * f)          # halo row
-    # deg>f rows: f distinct picks, all from that row's CSR slice
-    for r, lo, hi in [(1, 2, 10), (5, 14, 19)]:
-        picks = out[r]
-        assert len(set(picks.tolist())) == f
-        assert set(picks.tolist()) <= set(indices[lo:hi].tolist())
-
-
-def test_draw_neighbors_device_kernel_matches_jnp_ref():
-    """use_kernel=True and use_kernel=False draw identical neighbors
-    (the Pallas key kernel and the jnp oracle are bit-equal)."""
-    from repro.kernels.sample_draw import draw_neighbors_device
-    rng = np.random.default_rng(9)
-    nv = 60
-    deg = rng.integers(0, 12, nv)
-    indptr = np.zeros(nv + 1, np.int64)
-    indptr[1:] = np.cumsum(deg)
-    indices = rng.integers(0, nv + 20, indptr[-1])
-    wtab = jnp.asarray(1.0 + rng.random(nv + 20), jnp.float32)
-    cur = jnp.asarray(rng.integers(-1, nv + 10, 40), jnp.int32)
-    for policy in ("uniform", "labor", "cv"):
-        outs = [np.asarray(draw_neighbors_device(
-            jnp.asarray(indptr, jnp.int32), jnp.asarray(indices, jnp.int32),
-            wtab, cur, jnp.uint32(7), None, f=5, num_solid=nv,
-            width=int(deg.max()), policy=policy, use_kernel=uk))
-            for uk in (True, False)]
-        np.testing.assert_array_equal(outs[0], outs[1])
-
-
-def test_draw_neighbors_device_width_narrower_than_fanout():
-    """width < f widens the candidate matrix with -1 pads instead of
-    failing in top_k."""
-    from repro.kernels.sample_draw import draw_neighbors_device
-    indptr = jnp.asarray([0, 2, 3], jnp.int32)
-    indices = jnp.asarray([5, 1, 0], jnp.int32)
-    out = np.asarray(draw_neighbors_device(
-        indptr, indices, jnp.ones((6,), jnp.float32),
-        jnp.asarray([0, 1], jnp.int32), jnp.uint32(1), None,
-        f=4, num_solid=2, width=2, policy="uniform"))
-    np.testing.assert_array_equal(out[0], [5, 1, -1, -1])
-    np.testing.assert_array_equal(out[1], [0, -1, -1, -1])

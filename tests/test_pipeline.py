@@ -2,9 +2,10 @@
 
 Covers: vectorized-sampler parity with the reference ``sample_blocks``
 contract (shapes, masks, dst-prefix, halo-leaf, edge-existence, fanout
-bound, take-all rows) and statistics; prefetcher determinism for any
-worker count; empty-batch padding for rank imbalance; and end-to-end
-bit-identical loss curves pipelined vs the synchronous fallback.
+bound, take-all rows) at three graph/fanout shapes, and statistics; the
+host draw's pinned trace; prefetcher determinism for any worker count;
+epoch schedules and empty-batch padding for rank imbalance; and
+end-to-end bit-identical loss curves for inline vs prefetched sampling.
 """
 import numpy as np
 import pytest
@@ -34,49 +35,77 @@ def part(ps):
 
 
 @pytest.fixture(scope="module")
-def vec_mb(part):
+def ps4():
+    """Four parts of a graph of average degree 20: most rows draw at the
+    cells' fanouts, and halos are common.  The partitions' CSR holds both
+    directions of each generated edge, so a generator degree of 8 gives
+    rank 0 an average of 19.9 (and rows at or below 15 to take whole)."""
+    g = synthetic_graph(num_vertices=2000, avg_degree=8, num_classes=4,
+                        feat_dim=8, seed=6)
+    return partition_graph(g, 4, seed=0)
+
+
+# (partition set fixture, fanouts): the 2-part graph at the small fanouts
+# and at the cells' 3-layer fanouts, and the 4-part degree-20 graph
+_SHAPES = {"2part-f4,6": ("ps", FANOUTS),
+           "2part-f5,10,15": ("ps", (5, 10, 15)),
+           "4part-deg20-f5,10,15": ("ps4", (5, 10, 15))}
+
+
+@pytest.fixture(scope="module", params=list(_SHAPES), ids=list(_SHAPES))
+def vec_mb(request):
+    """``(part, fanouts, blocks)``: one minibatch of rank 0's first seed
+    batch, drawn by the host sampler at each of ``_SHAPES``."""
+    ps_name, fanouts = _SHAPES[request.param]
+    part = request.getfixturevalue(ps_name).parts[0]
     rng = np.random.default_rng(0)
     seeds = epoch_minibatches(part, BATCH, rng)[0]
-    return sample_blocks_vectorized(part, seeds, FANOUTS, rng, BATCH)
+    return part, fanouts, sample_blocks_vectorized(part, seeds, fanouts,
+                                                   rng, BATCH)
 
 
 def test_shapes_and_masks(vec_mb):
-    caps = layer_capacities(BATCH, FANOUTS)
-    assert [len(n) for n in vec_mb.layer_nodes] == caps
-    for nodes, mask in zip(vec_mb.layer_nodes, vec_mb.node_mask):
+    _, fanouts, mb = vec_mb
+    caps = layer_capacities(BATCH, fanouts)
+    assert [len(n) for n in mb.layer_nodes] == caps
+    for nodes, mask in zip(mb.layer_nodes, mb.node_mask):
         assert ((nodes >= 0) == mask).all()
-    assert vec_mb.nbr_idx[0].shape == (caps[1], FANOUTS[0])
-    assert vec_mb.nbr_idx[1].shape == (caps[2], FANOUTS[1])
+    for k, f in enumerate(fanouts):
+        assert mb.nbr_idx[k].shape == (caps[k + 1], f)
 
 
 def test_dst_prefix_property(vec_mb):
-    for k in range(len(vec_mb.nbr_idx)):
-        coarse, fine = vec_mb.layer_nodes[k + 1], vec_mb.layer_nodes[k]
+    _, _, mb = vec_mb
+    for k in range(len(mb.nbr_idx)):
+        coarse, fine = mb.layer_nodes[k + 1], mb.layer_nodes[k]
         assert (fine[:len(coarse)] == coarse).all()
 
 
 def test_fanout_bound(vec_mb):
-    for k, f in enumerate(FANOUTS):
-        assert (vec_mb.nbr_idx[k] >= 0).sum(1).max() <= f
+    _, fanouts, mb = vec_mb
+    for k, f in enumerate(fanouts):
+        assert (mb.nbr_idx[k] >= 0).sum(1).max() <= f
 
 
-def test_halos_never_expanded(part, vec_mb):
-    for k in range(len(vec_mb.nbr_idx)):
-        dsts = vec_mb.layer_nodes[k + 1]
+def test_halos_never_expanded(vec_mb):
+    part, _, mb = vec_mb
+    for k in range(len(mb.nbr_idx)):
+        dsts = mb.layer_nodes[k + 1]
         halo_dst = (dsts >= part.num_solid) & (dsts >= 0)
-        assert (vec_mb.nbr_idx[k][halo_dst] < 0).all()
+        assert (mb.nbr_idx[k][halo_dst] < 0).all()
 
 
-def test_sampled_edges_exist_no_replacement(part, vec_mb):
-    for k, f in enumerate(FANOUTS):
-        fine = vec_mb.layer_nodes[k]
-        dsts = vec_mb.layer_nodes[k + 1]
+def test_sampled_edges_exist_no_replacement(vec_mb):
+    part, fanouts, mb = vec_mb
+    for k, f in enumerate(fanouts):
+        fine = mb.layer_nodes[k]
+        dsts = mb.layer_nodes[k + 1]
         for r in range(len(dsts)):
             v = dsts[r]
             if v < 0 or v >= part.num_solid:
                 continue
             row = part.indices[part.indptr[v]:part.indptr[v + 1]]
-            got = vec_mb.nbr_idx[k][r]
+            got = mb.nbr_idx[k][r]
             got_vids = fine[got[got >= 0]].tolist()
             assert set(got_vids) <= set(row.tolist())
             assert len(set(got_vids)) == len(got_vids)   # w/o replacement
@@ -150,6 +179,39 @@ def test_epoch_schedule_pads_short_ranks():
         assert len(sched[k][1]) == 0
 
 
+@pytest.mark.parametrize("parts", [1, 2, 4])
+def test_epoch_schedule_covers_train_once(parts):
+    """Each rank's training vertices appear exactly once in an epoch of
+    ``SamplingPlan.epoch_schedule``, one seed batch per rank a step; a
+    rank past its own batches gets empty ones.  Beyond one part, the last
+    rank keeps a third of its training vertices so that it is padded."""
+    g = synthetic_graph(num_vertices=1200, avg_degree=6, num_classes=4,
+                        feat_dim=8, seed=2)
+    ps_n = partition_graph(g, parts, seed=0)
+    if parts > 1:
+        last = ps_n.parts[-1].train_mask
+        last[np.flatnonzero(last)[len(np.flatnonzero(last)) // 3:]] = False
+    bs = 8
+    cfg = small_gnn_config("graphsage", batch_size=bs, feat_dim=8,
+                           num_classes=4)
+    plan = SamplingPlan(ps=ps_n, cfg=cfg, base_seed=0)
+    counts = [int(np.ceil(p.train_mask.sum() / bs)) for p in ps_n.parts]
+    for epoch in (0, 1):
+        sched = plan.epoch_schedule(epoch)
+        assert len(sched) == max(counts)
+        assert all(len(row) == parts for row in sched)
+        for r, part in enumerate(ps_n.parts):
+            got = np.concatenate([row[r] for row in sched])
+            assert len(got) == len(np.unique(got))
+            np.testing.assert_array_equal(np.sort(got),
+                                          np.flatnonzero(part.train_mask))
+            assert all(len(sched[k][r]) > 0 for k in range(counts[r]))
+            assert all(len(sched[k][r]) == 0
+                       for k in range(counts[r], len(sched)))
+    if parts > 1:
+        assert counts[-1] < max(counts)         # the padding is exercised
+
+
 def test_empty_padded_batch_step_is_finite():
     """A fully masked batch through the compiled step: zero examples, zero
     loss, finite params — the all-masked path the padding fix relies on."""
@@ -191,8 +253,10 @@ def test_stack_ranks_layout(ps):
         assert mbh["node_mask"][k].dtype == np.bool_
 
 
-def test_train_bit_identical_sync_vs_pipelined():
-    """Pipelined epochs == synchronous fallback (0 workers), bit for bit."""
+@pytest.mark.parametrize("model", ["graphsage", "gat"])
+def test_train_bit_identical_sync_vs_pipelined(model):
+    """Prefetched epochs (1 and 4 workers) == inline sampling (0 workers),
+    bit for bit, in training and evaluation."""
     import jax
     from repro.train.gnn_trainer import DistTrainer, build_dist_data
 
@@ -201,11 +265,10 @@ def test_train_bit_identical_sync_vs_pipelined():
     ps1 = partition_graph(g, 1, seed=0)
     mesh = jax.make_mesh((1,), ("data",))
 
-    def run(workers, double_buffer):
+    def run(workers):
         cfg = small_gnn_config(
-            "graphsage", batch_size=48, feat_dim=16, num_classes=4,
-            pipeline=PipelineConfig(num_workers=workers, prefetch_depth=3,
-                                    double_buffer=double_buffer))
+            model, batch_size=48, feat_dim=16, num_classes=4,
+            pipeline=PipelineConfig(num_workers=workers, prefetch_depth=3))
         dd = build_dist_data(ps1, cfg)
         tr = DistTrainer(cfg=cfg, mesh=mesh, num_ranks=1, mode="aep")
         state = tr.init_state(jax.random.key(0))
@@ -213,9 +276,9 @@ def test_train_bit_identical_sync_vs_pipelined():
         acc = tr.evaluate(ps1, dd, state, num_batches=2)
         return [h["loss"] for h in hist], acc
 
-    loss_sync, acc_sync = run(0, double_buffer=False)
-    loss_1w, acc_1w = run(1, double_buffer=True)
-    loss_4w, acc_4w = run(4, double_buffer=True)
+    loss_sync, acc_sync = run(0)
+    loss_1w, acc_1w = run(1)
+    loss_4w, acc_4w = run(4)
     assert loss_sync == loss_1w == loss_4w
     assert acc_sync == acc_1w == acc_4w
     assert loss_sync[-1] < loss_sync[0]       # actually learns
@@ -349,6 +412,30 @@ def test_host_draw_leaves_stay_empty():
                                   + [-1] * 3)
 
 
+def test_host_draw_pinned_trace():
+    """The host draw's exact output for a fixed stream never drifts: any
+    change to Floyd's draw order moves every minibatch of every run.
+    Rows of degree 2, 3 (= f), 5 and 8, a halo, a degree-4 row with
+    ``allow=False`` and ``-1`` padding."""
+    indptr = np.array([0, 2, 5, 10, 18, 22])
+    indices = np.array([7, 1,  0, 2, 9,  4, 8, 3, 6, 1,
+                        2, 5, 9, 0, 7, 3, 6, 8,  1, 2, 3, 0])
+    cur = np.array([3, 0, 1, 2, 6, 4, -1, 3, 2])        # 6: a halo
+    allow = np.array([True] * 5 + [False] + [True] * 3)
+    out = _draw_neighbors(indptr, indices, cur, 5, 3,
+                          np.random.default_rng(2024), allow=allow)
+    want = np.array([[5, 9, 8],            # row 3, degree 8
+                     [7, 1, -1],           # row 0, degree 2: whole row
+                     [0, 2, 9],            # row 1, degree 3: whole row
+                     [3, 8, 1],            # row 2, degree 5
+                     [-1, -1, -1],         # halo
+                     [-1, -1, -1],         # row 4, allow=False
+                     [-1, -1, -1],         # padding
+                     [2, 6, 8],            # row 3 again, a fresh draw
+                     [4, 6, 1]])           # row 2 again
+    np.testing.assert_array_equal(out, want)
+
+
 @pytest.mark.parametrize("f", [1, 2, 5, 10])
 def test_host_draw_degree_at_and_past_fanout(f):
     """``deg == f`` takes the whole row in CSR order with no draw;
@@ -393,17 +480,18 @@ def test_plan_sample_host_same_any_worker_count(ps, workers):
                 np.testing.assert_array_equal(x, y)
 
 
-def test_sample_rows_counter_adds_up(part, vec_mb):
+def test_sample_rows_counter_adds_up(vec_mb):
     """``sample_rows{path}`` grows by the rows each layer expands:
     ``draw`` by those with ``deg > f``, ``all`` by the take-all rows."""
     from repro import obs
+    part, fanouts, mb = vec_mb
     obs.configure()
     try:
         reg = obs.get().registry
         deg = part.indptr[1:] - part.indptr[:-1]
         seen = []
-        for k, f in enumerate(FANOUTS):
-            cur = vec_mb.layer_nodes[k + 1]
+        for k, f in enumerate(fanouts):
+            cur = mb.layer_nodes[k + 1]
             solid = (cur >= 0) & (cur < part.num_solid)
             d = np.where(solid, deg[np.where(solid, cur, 0)], 0)
             before = {p: reg.value("sample_rows", path=p)
@@ -419,165 +507,3 @@ def test_sample_rows_counter_adds_up(part, vec_mb):
         assert min(seen[0::2]) > 0 and max(seen[1::2]) > 0
     finally:
         obs.configure()
-
-
-# ---------------------------------------------------------------------------
-# PR 9: on-device fanout draw (device_draw=True) + sampler policies
-# ---------------------------------------------------------------------------
-def _dev_cfg(policy="uniform", workers=1):
-    from repro.configs.gnn import SamplerConfig
-    return small_gnn_config(
-        "graphsage", batch_size=BATCH, feat_dim=8, num_classes=4,
-        fanouts=FANOUTS,
-        pipeline=PipelineConfig(
-            num_workers=workers, prefetch_depth=2,
-            sampler=SamplerConfig(policy=policy, device_draw=True)))
-
-
-def test_device_draw_bitreproducible_any_worker_count(ps):
-    """With device_draw on, an epoch of host batches is bit-identical for
-    0/1/4 prefetch workers AND across fresh plan instances — the device
-    draw depends only on (base_seed, epoch, step, rank, layer)."""
-    plan = SamplingPlan(ps=ps, cfg=_dev_cfg(), base_seed=4)
-    sched = plan.epoch_schedule(0)
-    n = min(4, len(sched))
-
-    def epoch_draws(p):
-        def run(workers):
-            make = lambda step: p.sample_host(0, step, sched[step])
-            return [b["nbr_idx"][0] for b in prefetch(make, n, workers, 2)]
-        return run
-    base = epoch_draws(plan)(0)
-    for w in (1, 4):
-        for a, b in zip(base, epoch_draws(plan)(w)):
-            np.testing.assert_array_equal(a, b)
-    plan2 = SamplingPlan(ps=ps, cfg=_dev_cfg(), base_seed=4)
-    for a, b in zip(base, epoch_draws(plan2)(0)):
-        np.testing.assert_array_equal(a, b)
-    # a different epoch draws different bits
-    other = plan.sample_host(1, 0, sched[0])
-    assert not np.array_equal(base[0], other["nbr_idx"][0])
-
-
-def test_device_draw_uniform_pinned_trace():
-    """Pinned reference trace: the uniform device draw for a fixed
-    (graph, base_seed, epoch, step) must never drift — it is part of the
-    checkpoint-compatibility surface.  Pinned under jax >= 0.5, whose
-    default ``jax_threefry_partitionable=True`` derives other bits from the
-    same fold_in chain than the 0.4 default did."""
-    from repro.pipeline.vectorized_sampler import DeviceSampler
-    g = synthetic_graph(num_vertices=300, avg_degree=5, num_classes=4,
-                        feat_dim=8, seed=11)
-    part = partition_graph(g, 1, seed=0).parts[0]
-    dev = DeviceSampler(part, base_seed=13)
-    out = dev.draw(2, 3, 0, np.arange(8, dtype=np.int64), 4)
-    want = np.array([[147, 176, 243, 235],
-                     [225, 212,  95,  82],
-                     [130, 174,  87, 274],
-                     [ 96, 115, 270,  30],
-                     [247, 111, 289, 229],
-                     [ 23, 144, 290,  80],
-                     [289, 266,  35,  54],
-                     [ 79,   1,  64,  98]])
-    np.testing.assert_array_equal(np.asarray(out), want)
-
-
-def _draw_union(part, policy, resident=None, steps=20, n_cur=64, f=3,
-                seed=0):
-    from repro.pipeline.vectorized_sampler import DeviceSampler
-    rng = np.random.default_rng(seed)
-    dev = DeviceSampler(part, base_seed=1, policy=policy)
-    if resident is not None:
-        dev.set_residency(resident)
-    picks = []
-    for s in range(steps):
-        cur = rng.integers(0, part.num_solid, n_cur)
-        out = np.asarray(dev.draw(0, s, 0, cur, f))
-        picks.append(out[out >= 0])
-    return picks
-
-
-@pytest.fixture(scope="module")
-def dense_part():
-    g = synthetic_graph(num_vertices=400, avg_degree=20, num_classes=4,
-                        feat_dim=8, seed=2)
-    return partition_graph(g, 1, seed=0).parts[0]
-
-
-def test_labor_shrinks_frontier_vs_uniform(dense_part):
-    """LABOR keys are shared per *vertex*, so overlapping fanouts re-pick
-    the same neighbors: per-step frontier (unique sampled vids) must be
-    measurably smaller than the uniform policy's."""
-    uni = _draw_union(dense_part, "uniform")
-    lab = _draw_union(dense_part, "labor")
-    u = np.mean([len(np.unique(p)) for p in uni])
-    l = np.mean([len(np.unique(p)) for p in lab])
-    assert l < 0.9 * u, f"labor frontier {l:.1f} !< 0.9 * uniform {u:.1f}"
-
-
-def test_cv_policy_prefers_resident_vertices(dense_part):
-    """cv divides LABOR keys by 1 + cv_boost * resident: HEC-resident
-    vertices must be sampled disproportionately often."""
-    nv = dense_part.num_solid + dense_part.num_halo
-    rng = np.random.default_rng(8)
-    resident = rng.random(nv) < 0.3
-    picks = np.concatenate(_draw_union(dense_part, "cv", resident=resident,
-                                       steps=30))
-    got_res = resident[picks].mean()
-    # base rate of resident vids among *available* neighbors
-    base = resident[dense_part.indices].mean()
-    assert got_res > base + 0.15, (
-        f"cv picked residents at {got_res:.2f}, base rate {base:.2f}")
-    # sanity: the uniform policy tracks the base rate
-    upicks = np.concatenate(_draw_union(dense_part, "uniform", steps=30))
-    assert abs(resident[upicks].mean() - base) < 0.1
-
-
-def test_uniform_inclusion_probability(dense_part):
-    """Uniform device draw: every neighbor of a fixed high-degree vertex
-    is included with probability ~ f/deg across steps."""
-    from repro.pipeline.vectorized_sampler import DeviceSampler
-    part = dense_part
-    deg = part.indptr[1:] - part.indptr[:-1]
-    v = int(np.argmax(deg[:part.num_solid]))
-    row = part.indices[part.indptr[v]:part.indptr[v + 1]]
-    f, steps = 4, 400
-    dev = DeviceSampler(part, base_seed=3)
-    cur = np.asarray([v], np.int64)
-    hits = np.zeros(len(row))
-    for s in range(steps):
-        out = np.asarray(dev.draw(0, s, 0, cur, f))[0]
-        for x in out[out >= 0]:
-            hits[np.flatnonzero(row == x)[0]] += 1
-    p = hits / steps
-    expect = f / len(row)
-    np.testing.assert_allclose(p.mean(), expect, rtol=0.05)
-    assert p.max() < 3.5 * expect        # no vertex systematically favored
-
-
-def test_train_bit_identical_device_draw_any_workers():
-    """End-to-end: device_draw training losses are bit-identical for any
-    worker count (the fold_in chain ignores prefetch order)."""
-    import jax
-    from repro.train.gnn_trainer import DistTrainer, build_dist_data
-
-    g = synthetic_graph(num_vertices=900, avg_degree=6, num_classes=4,
-                        feat_dim=8, seed=9)
-    ps1 = partition_graph(g, 1, seed=0)
-    mesh = jax.make_mesh((1,), ("data",))
-
-    def run(workers):
-        from repro.configs.gnn import SamplerConfig
-        cfg = small_gnn_config(
-            "graphsage", batch_size=32, feat_dim=8, num_classes=4,
-            fanouts=FANOUTS,
-            pipeline=PipelineConfig(
-                num_workers=workers, prefetch_depth=2,
-                sampler=SamplerConfig(device_draw=True)))
-        dd = build_dist_data(ps1, cfg)
-        tr = DistTrainer(cfg=cfg, mesh=mesh, num_ranks=1, mode="aep")
-        state = tr.init_state(jax.random.key(0))
-        _, hist = tr.train_epochs(ps1, dd, state, 1)
-        return [h["loss"] for h in hist]
-
-    assert run(0) == run(3)

@@ -94,19 +94,6 @@ def test_hec_probe_compiles(one_chip):
     _compile_mosaic(hec_probe, state, _spec(one_chip, (4, 256), jnp.int32))
 
 
-@pytest.mark.parametrize("policy", ["uniform", "cv"])
-def test_sample_keys_compiles(one_chip, policy):
-    """The device draw's key kernel for the 16000-row middle frontier;
-    120 is the synthetic generator's degree cap at average degree 15."""
-    from repro.kernels.sample_draw import sample_keys_kernel
-    n, width = N2, 120
-    _compile_mosaic(
-        lambda seed, nbr, w: sample_keys_kernel(seed, nbr, w, policy=policy),
-        _spec(one_chip, (), jnp.uint32),
-        _spec(one_chip, (n, width), jnp.int32),
-        _spec(one_chip, (n, width)))
-
-
 def test_gat_edge_compiles(one_chip):
     """GAT_PAPERS100M's last hidden layer: 4 heads of 256."""
     from repro.kernels.ops import gat_edge_aggregate
