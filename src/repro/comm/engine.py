@@ -76,6 +76,12 @@ from repro.comm.plan import ExchangePlan, build_exchange_plan
 from repro.core import aep
 
 
+def row_vids(vid_o, nodes):
+    """VID_o key of each sampled row (VID_p ``nodes``; -1 where padded)."""
+    return jnp.where(nodes >= 0, vid_o[jnp.clip(nodes, 0, vid_o.shape[0] - 1)],
+                     -1)
+
+
 def _tags_to_f32(tags):
     """int32 ``[..., n]`` -> float32 ``[..., 2n]``: high and low 16-bit
     halves as exact float values.  A bitcast would turn small tags into
@@ -149,7 +155,7 @@ class HaloExchangeEngine:
 
     # -- AEP push (device, inside shard_map) -----------------------------------
     def select_push(self, data: dict, mb: dict, captured: dict,
-                    vid_o_nodes, num_solid, seed, dims, dmax: int, me):
+                    num_solid, seed, dims, dmax: int, me):
         """Per-remote-rank reservoir selection of up to ``nc`` solid
         embeddings this rank owes (paper lines 14-20).  Membership in the
         push contract is ONE gather into the precomputed ``push_mask``."""
@@ -158,7 +164,6 @@ class HaloExchangeEngine:
         nc = self.push_limit
         nodes0 = mb["layer_nodes"][0]
         mask0 = mb["node_mask"][0]
-        vid0 = vid_o_nodes[0]
         is_solid = (nodes0 < num_solid) & (nodes0 >= 0) & mask0
         N0 = nodes0.shape[0]
         key = jax.random.fold_in(
@@ -171,7 +176,8 @@ class HaloExchangeEngine:
         score = jnp.where(member, u, -1.0)           # [R, N0]
         topv, topi = jax.lax.top_k(score, nc)        # [R, nc]
         ok0 = topv > 0
-        base_tags = jnp.where(ok0, vid0[topi], -1)
+        # VID_o keys of the winners only: [R, nc], not every sampled row
+        base_tags = jnp.where(ok0, row_vids(data["vid_o"], nodes0[topi]), -1)
         pos = jnp.where(ok0, topi, 0)
         base_ok = base_tags >= 0
 
@@ -187,8 +193,8 @@ class HaloExchangeEngine:
             tags = tags.at[:, l].set(jnp.where(ok, base_tags, -1))
         return tags, embs
 
-    def select_hot_push(self, data, mb, captured, vid_o_nodes, num_solid,
-                        seed, dims, dmax: int, me):
+    def select_hot_push(self, data, mb, captured, num_solid, seed, dims,
+                        dmax: int, me):
         """Reservoir-select up to ``hot_budget`` of this rank's *owned* hot
         vertices present in the minibatch; every rank will receive the same
         rows (broadcast refresh).  Tags are dense tier SLOT indices, not
@@ -197,7 +203,7 @@ class HaloExchangeEngine:
         hb = self.hot_budget
         nodes0 = mb["layer_nodes"][0]
         mask0 = mb["node_mask"][0]
-        vid0 = vid_o_nodes[0]
+        vid0 = row_vids(data["vid_o"], nodes0)
         is_solid = (nodes0 < num_solid) & (nodes0 >= 0) & mask0
         slot, is_hot = hot_lib.tier_slots(data["hot_vids"], vid0)
         mine = data["hot_mine"][slot] & is_hot & is_solid
@@ -258,8 +264,8 @@ class HaloExchangeEngine:
         rec_hot_embs = rec[:, o + 2 * L * hb:].reshape(R, L, hb, dmax)
         return rec_tags, rec_embs, rec_hot_tags, rec_hot_embs
 
-    def aep_push(self, data, mb, captured, vid_o_nodes, num_solid, inflight,
-                 seed, dims, dmax, me, fault_code=None):
+    def aep_push(self, data, mb, captured, num_solid, inflight, seed, dims,
+                 dmax, me, fault_code=None):
         """Select + fused-push + enqueue; returns ``(inflight, stats)``.
 
         ``stats['push_rows']`` / ``stats['push_bytes']`` measure the
@@ -280,8 +286,8 @@ class HaloExchangeEngine:
         from repro.resilience.inject import (CODE_CORRUPT_PUSH,
                                              CODE_DROP_PUSH)
         with jax.named_scope("aep_pack"):
-            tags, embs = self.select_push(data, mb, captured, vid_o_nodes,
-                                          num_solid, seed, dims, dmax, me)
+            tags, embs = self.select_push(data, mb, captured, num_solid,
+                                          seed, dims, dmax, me)
         if fault_code is not None:
             rowok = jnp.isfinite(embs).all(axis=-1)       # [R, L, nc]
             tags = jnp.where(rowok, tags, -1)
@@ -301,8 +307,7 @@ class HaloExchangeEngine:
         if self.hot_budget and "hot_tags" in inflight:
             with jax.named_scope("aep_pack"):
                 h_tags, h_embs = self.select_hot_push(
-                    data, mb, captured, vid_o_nodes, num_solid, seed, dims,
-                    dmax, me)
+                    data, mb, captured, num_solid, seed, dims, dmax, me)
             if fault_code is not None:
                 # NaN containment for the broadcast segment too (wire
                 # faults target only the pairwise payload)

@@ -43,10 +43,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.cache import hec as hec_lib
 from repro.cache import hot_tier as hot_lib
-from repro.comm.engine import HaloExchangeEngine
+from repro.comm.engine import HaloExchangeEngine, row_vids
 from repro.comm.plan import _pad_stack, build_exchange_plan
 from repro.configs.gnn import GNNConfig
 from repro.graph.partition import PartitionSet
+from repro.graph.sampling import layer_capacities
 from repro.pipeline.staging import MinibatchPipeline
 from repro.resilience.inject import CODE_NAN_STEP
 from repro.models.gnn import gat as gat_lib
@@ -82,6 +83,38 @@ def build_dist_data(ps: PartitionSet, cfg: GNNConfig, mesh=None) -> dict:
                              for p in ps.parts], -1),
     }
     return {**jax.device_put(host, sharding), **plan_tables}
+
+
+def has_halos(dist_data) -> bool:
+    """Whether any rank holds a halo vertex: a rank's ``vid_o`` rows past
+    its solids are its halos.  Read once from the host, when the step is
+    built; without ``dist_data`` the answer is yes, which is always exact
+    (probing a layer with no halos substitutes nothing)."""
+    if dist_data is None:
+        return True
+    rows = (np.asarray(dist_data["vid_o"]) >= 0).sum(axis=1)
+    return bool((rows > np.asarray(dist_data["num_solid"])).any())
+
+
+def substitute_halos(h, nodes, is_halo, data, hec, hot, life_span):
+    """Replace a layer's halo rows of ``h`` by fresh hot-tier replicas,
+    else by HEC hits, probing every row by its VID_o key; returns
+    ``(h, use, use_hot)``, the rows the HEC and the tier served.  ``hot``
+    is the layer's tier state or ``None``."""
+    with jax.named_scope("hec_lookup"):
+        keys = row_vids(data["vid_o"], nodes)
+        dim = h.shape[1]
+        if hot is not None:
+            t_hit, t_emb = hot_lib.tier_lookup(hot, data["hot_vids"], keys,
+                                               life_span)
+            use_hot = is_halo & t_hit
+            h = jnp.where(use_hot[:, None], t_emb[:, :dim], h)
+        else:
+            use_hot = jnp.zeros_like(is_halo)
+        hit, emb = hec_lib.hec_lookup(hec, keys)
+        use = is_halo & hit & ~use_hot
+        h = jnp.where(use[:, None], emb[:, :dim], h)
+    return h, use, use_hot
 
 
 def _epoch_mean(ep_metrics):
@@ -249,7 +282,10 @@ class DistTrainer:
 
     # -- per-rank step body (inside shard_map) ------------------------------
     def _rank_step(self, params, opt_state, hec, hot, inflight, data, mb,
-                   seed, fault=None):
+                   seed, fault=None, *, probe_halos):
+        """One rank's training step.  ``probe_halos`` (static,
+        :func:`has_halos`) False compiles no HEC or tier probe at all:
+        with no halo on any rank none could substitute a row."""
         cfg = self.cfg
         L = cfg.num_layers
         dims = layer_dims(cfg)
@@ -264,7 +300,6 @@ class DistTrainer:
         fcode = sq(fault) if fault is not None else None
 
         num_solid = data["num_solid"]
-        P_max = data["vid_o"].shape[0]
 
         # (1) HEC tick + consume the delayed push (paper lines 8-9); the
         # hot tier ticks/consumes its broadcast segment the same way
@@ -285,38 +320,24 @@ class DistTrainer:
             solid_idx = jnp.clip(nodes0, 0, data["features"].shape[0] - 1)
             h0 = data["features"][solid_idx] * (mask0 & ~is_halo0)[:, None]
         valid0 = mask0 & ~is_halo0
-        # the HEC's keys: every sampled row's original vertex id
-        with jax.named_scope("hec_lookup"):
-            vid_o_nodes = [
-                jnp.where(n >= 0, data["vid_o"][jnp.clip(n, 0, P_max - 1)],
-                          -1)
-                for n in mb["layer_nodes"]]
 
-        def tier_sub(k, h, is_halo):
-            """Hot-tier substitution: a halo row whose hub embedding is
-            fresh in the local replica skips the HEC entirely."""
-            if not hot:
-                return h, jnp.zeros_like(is_halo)
-            t_hit, t_emb = hot_lib.tier_lookup(
-                hot[k], data["hot_vids"], vid_o_nodes[k],
-                cfg.hec.life_span)
-            use = is_halo & t_hit
-            h = jnp.where(use[:, None], t_emb[:, :h.shape[1]], h)
-            return h, use
+        def halo_sub(k, h, nodes, is_halo):
+            if not probe_halos:
+                none = jnp.zeros_like(is_halo)
+                return h, none, none
+            return substitute_halos(h, nodes, is_halo, data, hec[k],
+                                    hot[k] if hot else None,
+                                    cfg.hec.life_span)
 
         zero = jnp.zeros((), jnp.int32)
         if self.mode == "aep":
-            with jax.named_scope("hec_lookup"):
-                h0, use_hot0 = tier_sub(0, h0, is_halo0)
-                hit0, emb0 = hec_lib.hec_lookup(hec[0], vid_o_nodes[0])
-                use0 = is_halo0 & hit0 & ~use_hot0
-                h0 = jnp.where(use0[:, None], emb0, h0)
+            h0, use0, use_hot0 = halo_sub(0, h0, nodes0, is_halo0)
             valid0 = valid0 | use0 | use_hot0
             hits0 = (jnp.sum(use0 | use_hot0), jnp.sum(is_halo0),
                      jnp.sum(use_hot0))
         elif self.mode == "sync":
-            h0, got = self.engine.sync_fetch(data, vid_o_nodes[0],
-                                             is_halo0, h0)
+            h0, got = self.engine.sync_fetch(
+                data, row_vids(data["vid_o"], nodes0), is_halo0, h0)
             valid0 = valid0 | got
             hits0 = (got.sum(), jnp.sum(is_halo0), zero)
         else:
@@ -342,11 +363,7 @@ class DistTrainer:
                 maskk = mb["node_mask"][k]
                 is_halo = (nodes_k >= num_solid) & maskk
                 if self.mode == "aep" and k < L:
-                    with jax.named_scope("hec_lookup"):
-                        h, use_hot = tier_sub(k, h, is_halo)
-                        hit, emb = hec_lib.hec_lookup(hec[k], vid_o_nodes[k])
-                        use = is_halo & hit & ~use_hot
-                        h = jnp.where(use[:, None], emb[:, :h.shape[1]], h)
+                    h, use, use_hot = halo_sub(k, h, nodes_k, is_halo)
                     valid = (valid & ~is_halo) | use | use_hot
                     hits.append((jnp.sum(use | use_hot), jnp.sum(is_halo),
                                  jnp.sum(use_hot)))
@@ -385,16 +402,16 @@ class DistTrainer:
             loss, vjp_fn, (nll_sum, correct, n_valid, captured, hits) = \
                 jax.vjp(loss_fn, params, has_aux=True)
             inflight, push_stats = self.engine.aep_push(
-                data, mb, captured, vid_o_nodes, num_solid, inflight, seed,
-                dims, dmax, me, fault_code=fcode)
+                data, mb, captured, num_solid, inflight, seed, dims, dmax,
+                me, fault_code=fcode)
             grads, = vjp_fn(jnp.ones_like(loss))
         else:
             (loss, (nll_sum, correct, n_valid, captured, hits)), grads = \
                 jax.value_and_grad(loss_fn, has_aux=True)(params)
             if self.mode == "aep":
                 inflight, push_stats = self.engine.aep_push(
-                    data, mb, captured, vid_o_nodes, num_solid, inflight,
-                    seed, dims, dmax, me, fault_code=fcode)
+                    data, mb, captured, num_solid, inflight, seed, dims,
+                    dmax, me, fault_code=fcode)
         # per-rank telemetry shard: the pre-psum values, captured BEFORE the
         # cross-rank reductions below and returned as one extra sharded
         # output.  The host reads it with the metrics it already transfers
@@ -499,6 +516,13 @@ class DistTrainer:
             if (self.mode == "aep" and self.engine.hot_budget) else 0
         armed = self.resilience is not None \
             and getattr(self.resilience, "step_armed", False)
+        # no rank holds a halo (one partition): the step probes nothing
+        probe = has_halos(dist_data)
+        if self.mode == "aep":
+            rows = layer_capacities(cfg.batch_size, cfg.fanouts)
+            for k in range(cfg.num_layers):
+                obs.set_gauge("hec_probe_rows", rows[k] if probe else 0,
+                              layer=k)
 
         if armed:
             # step-armed resilience: one extra sharded [R] int32 fault-code
@@ -507,7 +531,8 @@ class DistTrainer:
             def stepf(params, opt_state, hec, hot, inflight, data, mb,
                       seed, fault):
                 return self._rank_step(params, opt_state, hec, hot,
-                                       inflight, data, mb, seed, fault)
+                                       inflight, data, mb, seed, fault,
+                                       probe_halos=probe)
             in_specs = (repl, repl, [shard] * cfg.num_layers,
                         [shard] * hot_layers, shard, shard, shard, repl,
                         shard)
@@ -515,7 +540,8 @@ class DistTrainer:
             def stepf(params, opt_state, hec, hot, inflight, data, mb,
                       seed):
                 return self._rank_step(params, opt_state, hec, hot,
-                                       inflight, data, mb, seed)
+                                       inflight, data, mb, seed,
+                                       probe_halos=probe)
             in_specs = (repl, repl, [shard] * cfg.num_layers,
                         [shard] * hot_layers, shard, shard, shard, repl)
 
