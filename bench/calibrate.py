@@ -9,8 +9,11 @@ reference -- the program's reading.  For the first ``--stand-ins`` seeds
 also the readings of the stand-ins put in the program's place: the
 control (the reference in bfloat16), the reference with half of each
 batch left out, and, on several chips, with the exchange between chips
-left out.  One JSON line per seed on stdout.  Benchmark runs never run
-this; it needs the chips the cell asks for.
+left out.  Each reading is printed on stdout as soon as it exists, one
+JSON line each: the seed's program line, then one line per stand-in
+(``{"seed", "stand_in", "numbers", "seconds"}``), so that a call cut by
+its time limit keeps what it read.  Benchmark runs never run this; it
+needs the chips the cell asks for.
 """
 from __future__ import annotations
 
@@ -53,16 +56,23 @@ def main(argv=None):
                                     t0, clock)
         t1 = time.perf_counter()
         chk = check.Check(rec, ps, graph, m, c["hec"], seed)
-        out = {"seed": seed, "program": chk.program(),
-               "setup_s": run.setup_s, "reference_s": time.perf_counter() - t1,
-               "peak_bytes": run.memory_peak_bytes,
-               "losses": rec.losses, "ref_losses": chk.res.losses}
-        if i < args.stand_ins:
-            out["control"] = chk.control()
-            out["half_batch"] = chk.half_batch()
-            if cell["chips"] > 1:
-                out["no_exchange"] = chk.no_exchange()
-        print(json.dumps(out), flush=True)
+        print(json.dumps({
+            "seed": seed, "program": chk.program(), "setup_s": run.setup_s,
+            "reference_s": time.perf_counter() - t1,
+            "peak_bytes": run.memory_peak_bytes, "losses": rec.losses,
+            "ref_losses": chk.res.losses}), flush=True)
+        if i >= args.stand_ins:
+            continue
+        stand_ins = [chk.control, chk.half_batch]
+        if cell["chips"] > 1:
+            stand_ins.append(chk.no_exchange)
+        for stand_in in stand_ins:
+            t2 = time.perf_counter()
+            numbers = stand_in()
+            print(json.dumps({"seed": seed, "stand_in": stand_in.__name__,
+                              "numbers": numbers,
+                              "seconds": time.perf_counter() - t2}),
+                  flush=True)
 
 
 if __name__ == "__main__":

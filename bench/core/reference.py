@@ -1,23 +1,26 @@
-"""Plain float32 reference of the paper's training step (GraphSAGE, GAT).
+"""Plain float32 reference of the paper's training step.
 
-Written from the paper's equations, not imported from the program:
+The model's own maths, written from its equations and not imported from
+the program, is ``bench/models/<model>.py``, found by the configuration's
+``model`` name (:func:`load_model`).  It gives the weights
+(``init_params``), one layer's output for a block of destination rows
+(``block``), what a hidden layer's output passes through before dropout
+(``between``), the width of a halo row at each layer's input
+(``cache_dims``) and, for ``flops.py``, the forward multiply-adds
+(``forward_flops``); ``OPTIONS``, where it is there, names the
+configuration keys the model reads beyond the common sizes.
 
-    GraphSAGE (eq. 1)  h_v = Dropout(ReLU(W_n mean_{u in N(v)} h_u + W_s h_v + b))
-    GAT (eq. 2, with the paper's change: bias and ReLU before attention)
-        z_u = ReLU(W h_u + b);  alpha_uv = softmax_u(LeakyReLU(a_u.z_u + a_v.z_v))
-        h_v = sum_u alpha_uv z_u
+What is shared, and blind to the model: softmax cross-entropy over the
+seed rows, the gradient of the example-weighted mean over every rank's
+batch, and Adam with the gradient clipped to global norm 1.  Matrix
+products run at the precision the configuration states: float32 at the
+backend's default precision (on the TPU one bfloat16 pass of the inputs
+with float32 sums).
 
-then softmax cross-entropy over the seed rows, the gradient of the
-example-weighted mean over every rank's batch, and Adam with the gradient
-clipped to global norm 1.  Matrix products run at the precision the
-configuration states: float32 at the backend's default precision (on the
-TPU one bfloat16 pass of the inputs with float32 sums).
-
-The weights come from the reference's own initialisation (the published
-init: normal scaled by sqrt(2 / fan_in), zero biases, attention vectors
-scaled by dh^-0.5), drawn from ``--seed`` with ``jax.random`` in the same
-order as the program draws them.  Dropout is the position-hash mask the
-paper's fused UPDATE uses, so a given step drops the same units.
+The weights come from the model file's own initialisation (the published
+init), drawn from ``--seed`` with ``jax.random`` in the same order as the
+program draws them.  Dropout is the position-hash mask the paper's fused
+UPDATE uses, so a given step drops the same units.
 
 A rank's halo rows take the historical embedding the reference's own
 cache holds for them (:class:`RefCache`), filled by the reference's own
@@ -31,6 +34,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib.util
+import os
 from typing import Dict, List
 
 import jax
@@ -39,11 +44,28 @@ import numpy as np
 
 _MIX1 = np.uint32(0x85EBCA6B)
 _MIX2 = np.uint32(0xC2B2AE35)
+MODELS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "models")
+
+
+@functools.lru_cache(maxsize=None)
+def load_model(name: str):
+    """The model's maths, ``bench/models/<name>.py``; an error that names
+    that file where there is none."""
+    path = os.path.join(MODELS, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no maths for model {name!r}: {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_model_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass(frozen=True)
 class Model:
-    """The sizes the reference needs, from the configuration file."""
+    """The sizes the reference needs, from the configuration file, and
+    ``options``: (key, value) of each key the model's ``OPTIONS`` name,
+    a list as a tuple, so that the model is hashable."""
     model: str
     feat_dim: int
     hidden: int
@@ -54,51 +76,36 @@ class Model:
     batch_size: int
     dropout: float
     lr: float
+    options: tuple = ()
 
     @classmethod
     def from_config(cls, c: dict) -> "Model":
+        keys = getattr(load_model(c["model"]), "OPTIONS", ())
         return cls(model=c["model"], feat_dim=c["feat_dim"],
                    hidden=c["hidden_size"], num_classes=c["num_classes"],
                    num_layers=c["num_hidden_layers"] + 1,
                    num_heads=c["num_heads"], fanouts=tuple(c["fanouts"]),
                    batch_size=c["batch_size"], dropout=c["dropout"],
-                   lr=c["lr"])
+                   lr=c["lr"],
+                   options=tuple((k, tuple(c[k]) if isinstance(c[k], list)
+                                  else c[k]) for k in keys))
+
+    @property
+    def maths(self):
+        """The module ``bench/models/<model>.py``."""
+        return load_model(self.model)
 
     def cache_dims(self) -> List[int]:
         """Width of the embedding a halo row takes at each layer's input."""
-        hid = self.hidden * (self.num_heads if self.model == "gat" else 1)
-        return [self.feat_dim] + [hid] * (self.num_layers - 1)
+        return list(self.maths.cache_dims(self))
 
 
 # ---------------------------------------------------------------------------
 # weights
 # ---------------------------------------------------------------------------
 def init_params(key, m: Model):
-    L = m.num_layers
-    layers = []
-    if m.model == "graphsage":
-        dims = [m.feat_dim] + [m.hidden] * (L - 1) + [m.num_classes]
-        for l in range(L):
-            k1, k2, key = jax.random.split(key, 3)
-            s = (2.0 / dims[l]) ** 0.5
-            layers.append({
-                "wn": jax.random.normal(k1, (dims[l], dims[l + 1])) * s,
-                "ws": jax.random.normal(k2, (dims[l], dims[l + 1])) * s,
-                "b": jnp.zeros((dims[l + 1],))})
-    else:
-        d_in = [m.feat_dim] + [m.hidden * m.num_heads] * (L - 1)
-        d_out = [m.hidden] * (L - 1) + [m.num_classes]
-        heads = [m.num_heads] * (L - 1) + [1]
-        for l in range(L):
-            k1, k2, k3, key = jax.random.split(key, 4)
-            s = (2.0 / d_in[l]) ** 0.5
-            H, dh = heads[l], d_out[l]
-            layers.append({
-                "w": jax.random.normal(k1, (d_in[l], H, dh)) * s,
-                "b": jnp.zeros((H, dh)),
-                "a_u": jax.random.normal(k2, (H, dh)) * dh ** -0.5,
-                "a_v": jax.random.normal(k3, (H, dh)) * dh ** -0.5})
-    return {"layers": layers}
+    """The model's weights from ``key``, as the program draws them."""
+    return m.maths.init_params(key, m)
 
 
 # ---------------------------------------------------------------------------
@@ -125,28 +132,6 @@ def _dropout(x, rate, seed):
 
 
 BLOCK = 8000        # destination rows per block of a layer
-
-
-def _sage_block(p, h, valid, nbr, self_rows):
-    idx = jnp.maximum(nbr, 0)
-    m = ((nbr >= 0) & valid[idx]).astype(h.dtype)[..., None]
-    agg = (h[idx] * m).sum(1) / jnp.maximum(m.sum(1), 1)
-    return agg @ p["wn"] + h[self_rows] @ p["ws"] + p["b"]
-
-
-def _gat_block(p, h, valid, nbr, self_rows):
-    idx = jnp.maximum(nbr, 0)
-    z_src = jax.nn.relu(jnp.einsum("nfd,dhe->nfhe", h[idx], p["w"]) + p["b"])
-    z_dst = jax.nn.relu(jnp.einsum("nd,dhe->nhe", h[self_rows], p["w"])
-                        + p["b"])
-    e_u = (z_src * p["a_u"]).sum(-1)                     # [n, f, H]
-    e_v = (z_dst * p["a_v"]).sum(-1)                     # [n, H]
-    m = (nbr >= 0) & valid[idx]
-    s = jax.nn.leaky_relu(e_u + e_v[:, None, :], 0.2)
-    s = jnp.where(m[..., None], s, jnp.asarray(-1e30, s.dtype))
-    alpha = jnp.where(m[..., None], jax.nn.softmax(s, axis=1), 0)
-    out = jnp.einsum("nfh,nfhe->nhe", alpha, z_src)
-    return out.reshape(out.shape[0], -1)
 
 
 def _layer(block_fn, p, h, valid, nbr):
@@ -180,7 +165,8 @@ def rank_step(params, h0, valid0, nbr_idx, subs, labels, seed_mask, seed,
     of nll_sum, and per layer the pushed rows' (embedding, valid).
     ``precision``: float32 or bfloat16 (weights, activations and
     gradients in bfloat16)."""
-    block_fn = _sage_block if m.model == "graphsage" else _gat_block
+    maths = m.maths
+    block_fn = functools.partial(maths.block, m=m)
     dtype = jnp.bfloat16 if precision == "bfloat16" else jnp.float32
 
     def nll_sum(params):
@@ -192,11 +178,10 @@ def rank_step(params, h0, valid0, nbr_idx, subs, labels, seed_mask, seed,
             nbr = nbr_idx[k]
             last = k == L - 1
             h = _layer(block_fn, p["layers"][k], h, valid, nbr)
-            if not last and m.model == "graphsage":
-                h = jax.nn.relu(h)
             valid = valid[:nbr.shape[0]]
             if not last:
-                h = _dropout(h, m.dropout, seed + jnp.uint32(k + 1))
+                h = _dropout(maths.between(h, m), m.dropout,
+                             seed + jnp.uint32(k + 1))
                 is_halo, use, emb = subs[k]
                 h = jnp.where(use[:, None], emb.astype(dtype), h)
                 valid = (valid & ~is_halo) | use

@@ -3,8 +3,9 @@
     python bench/run.py --workload sage-1chip --seed 7 --seconds 30 --trace 0
 
 Loads the cell named in ``BENCHMARK.json`` (its configuration file under
-``bench/configs/``, its traffic file under ``bench/traffic/`` and its
-limits under ``bench/workloads/``), sets up and warms up the program,
+``bench/configs/``, its model's maths under ``bench/models/``, its
+traffic file under ``bench/traffic/`` and its limits under
+``bench/workloads/``), sets up and warms up the program,
 trains whole epochs for ``--seconds``, checks the first recorded steps
 against the plain reference, and prints one JSON line last on stdout:
 ``correct``, ``attempted``/``failed`` (window steps / steps of epochs
@@ -116,12 +117,14 @@ def main(argv=None) -> int:
     from core.flops import step_flops
     from core.reference import Model
 
+    config, traffic = cell["config"], cell["traffic"]
+    c = config["config"]
+    m = Model.from_config(c)
     device = dev.require_chips(cell["chips"])
     peaks = dev.peaks_for(device["kind"], os.path.join(BENCH, "peaks.json"))
     dev.set_compile_cache(os.path.join(session.CACHE, "jax"))
     clock = dev.CompileClock()
 
-    config, traffic = cell["config"], cell["traffic"]
     graph = session.load_graph(traffic)
     ps = session.load_partition(graph, traffic)
     cfg = session.build_config(config)
@@ -133,12 +136,8 @@ def main(argv=None) -> int:
                                 args.seconds, T_START, clock,
                                 trace_dir=trace_dir)
 
-    c = config["config"]
-    flops = step_flops(c["model"], c["batch_size"], c["fanouts"],
-                       c["feat_dim"], c["hidden_size"], c["num_classes"],
-                       c["num_heads"])
     record = {"run": run, "chips": cell["chips"], "peaks": peaks,
-              "flops_per_step": flops["total"], "trace": None}
+              "flops_per_step": step_flops(m)["total"], "trace": None}
     breakdown = None
     if trace_dir:
         record["trace"] = trace_lib.reduce(trace_dir, cell["chips"])
@@ -148,8 +147,7 @@ def main(argv=None) -> int:
     metrics = read_metrics(specs, record, cell["bench"])
 
     clock.live = False
-    numbers = check.Check(rec, ps, graph, Model.from_config(c), c["hec"],
-                          args.seed).program()
+    numbers = check.Check(rec, ps, graph, m, c["hec"], args.seed).program()
     correct, rows = check.judge(numbers, cell["limits"])
 
     per_epoch = run.steps / max(run.epochs, 1)

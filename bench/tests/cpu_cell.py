@@ -1,13 +1,18 @@
 """A benchmark run at test size on the CPU, with a fault planted on demand.
 
-    python bench/tests/cpu_cell.py <ranks> <graphsage|gat> <faults> <cache_dir>
+    python bench/tests/cpu_cell.py <ranks> <model> <faults> <cache_dir> [seed]
 
 Skips the harness's look for a chip and drives the rest of a run: the
 graph and partition through the cache, ``setup_gnn``, the warm-up epoch
 with the first three steps recorded, a short window, and the check
-against the reference.  Each of the comma-separated ``faults`` breaks the
-timed path underneath the harness for one run (all runs share one
-process and one compiled step):
+against the reference.  ``model`` is ``graphsage`` or ``gat`` at the
+small test size (two layers of 16, fanouts 3 and 4, on a 3,000-vertex
+graph), or a configuration file (``bench/configs/<name>.json``) whose
+model runs at the file's own layers, fanouts, widths, learning rate and
+dropout, on a 6,000-vertex graph; batch 32 and a test-sized HEC either
+way.  ``seed`` defaults to 2**31 + 11.  Each of the comma-separated
+``faults`` breaks the timed path underneath the harness for one run (all
+runs share one process and one compiled step):
 
   none          the program as it is
   frozen_state  the step returns the weights it was given
@@ -29,6 +34,7 @@ import time
 def main():
     ranks, model, faults, cache = (int(sys.argv[1]), sys.argv[2],
                                    sys.argv[3].split(","), sys.argv[4])
+    seed = int(sys.argv[5]) if len(sys.argv) > 5 else 2**31 + 11
     state = {"fault": None}
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ranks}"
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -52,10 +58,21 @@ def main():
 
     traffic = dict(num_vertices=3000, avg_degree=8, num_classes=8,
                    feat_dim=32, train_frac=0.1, graph_seed=0, parts=ranks)
+    sizes = dict(fanouts=(3, 4), hidden_size=16, num_heads=2, dropout=0.5)
+    if model.endswith(".json"):
+        with open(model) as f:
+            c = json.load(f)["config"]
+        model = c["model"]
+        traffic.update(num_vertices=6000, num_classes=c["num_classes"],
+                       feat_dim=c["feat_dim"])
+        sizes = {k: c[k] for k in ("hidden_size", "num_hidden_layers",
+                                   "num_heads", "lr", "dropout")}
+        sizes["fanouts"] = tuple(c["fanouts"])
     cfg = small_gnn_config(
-        model, fanouts=(3, 4), hidden_size=16, num_heads=2, batch_size=32,
-        dropout=0.5, hec=HECConfig(cache_size=65536, ways=8, life_span=2,
-                                   push_limit=64, delay=1))
+        model, batch_size=32, feat_dim=traffic["feat_dim"],
+        num_classes=traffic["num_classes"],
+        hec=HECConfig(cache_size=65536, ways=8, life_span=2, push_limit=64,
+                      delay=1), **sizes)
 
     make_step = DistTrainer.make_step
 
@@ -91,7 +108,6 @@ def main():
     ps = session.load_partition(g, traffic, cache)
     c = dataclasses.asdict(cfg)
     c["fanouts"] = list(c["fanouts"])
-    seed = 2**31 + 11
     out = {}
     for fault in faults:
         state["fault"] = fault

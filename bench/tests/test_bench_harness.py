@@ -137,6 +137,192 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
     assert got == {"steps_per_epoch": {"value": 19.0, "unit": "steps"}}
 
 
+TOY_MODEL = '''"""A toy: GraphSAGE with a sum aggregator, the self term weighted by
+(1 + eps), and tanh between layers."""
+import jax
+import jax.numpy as jnp
+
+OPTIONS = ("eps",)
+
+
+def _dims(m):
+    return [m.feat_dim] + [m.hidden] * (m.num_layers - 1) + [m.num_classes]
+
+
+def init_params(key, m):
+    dims = _dims(m)
+    layers = []
+    for l in range(m.num_layers):
+        k1, k2, key = jax.random.split(key, 3)
+        shape = (dims[l], dims[l + 1])
+        layers.append({"wn": jax.random.normal(k1, shape) / dims[l],
+                       "ws": jax.random.normal(k2, shape) / dims[l],
+                       "b": jnp.zeros((dims[l + 1],))})
+    return {"layers": layers}
+
+
+def block(p, h, valid, nbr, self_rows, m):
+    eps = dict(m.options)["eps"]
+    idx = jnp.maximum(nbr, 0)
+    mask = ((nbr >= 0) & valid[idx]).astype(h.dtype)[..., None]
+    return ((h[idx] * mask).sum(1) @ p["wn"]
+            + (1 + eps) * (h[self_rows] @ p["ws"]) + p["b"])
+
+
+def between(h, m):
+    return jnp.tanh(h)
+
+
+def cache_dims(m):
+    return _dims(m)[:-1]
+
+
+def forward_flops(m, caps):
+    dims = _dims(m)
+    L = m.num_layers
+    return {"projection": sum(2 * caps[k + 1] * dims[k] * dims[k + 1]
+                              for k in range(L)),
+            "aggregation": sum(caps[k + 1] * m.fanouts[k] * dims[k]
+                               for k in range(L))}
+'''
+
+
+def _load(path, name, monkeypatch):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    monkeypatch.setitem(sys.modules, name, mod)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_a_new_model_needs_only_new_files(tmp_path, monkeypatch):
+    """A model added as one file, ``bench/models/<model>.py``, is found by
+    the name in its configuration: the reference's steps follow the
+    toy's formula, written out here, and the FLOP count is the toy's own.
+    No existing file changes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns(".cache", "__pycache__"))
+    (root / "bench/models/sage_sum.py").write_text(TOY_MODEL)
+    ref = _load(root / "bench/core/reference.py", "copy_reference",
+                monkeypatch)
+    flops = _load(root / "bench/core/flops.py", "copy_flops", monkeypatch)
+    c = dict(model="sage_sum", feat_dim=6, hidden_size=5, num_classes=3,
+             num_hidden_layers=1, num_heads=1, fanouts=[2, 3], batch_size=4,
+             dropout=0.0, lr=0.05, eps=0.5)
+    m = ref.Model.from_config(c)
+    assert m.options == (("eps", 0.5),) and m.cache_dims() == [6, 5]
+    assert not os.path.exists(os.path.join(BENCH, "models", "sage_sum.py"))
+
+    assert flops.capacities(4, [2, 3]) == [48, 16, 4]
+    proj = 2 * (16 * 6 * 5 + 4 * 5 * 3)
+    agg = 16 * 2 * 6 + 4 * 3 * 5
+    assert flops.step_flops(m) == {"projection": 6.0 * proj,
+                                   "aggregation": 6.0 * agg,
+                                   "total": 6.0 * (proj + agg)}
+
+    rng = np.random.default_rng(3)
+    V, n = 40, (30, 12, 4)
+    features = rng.standard_normal((V, 6)).astype(np.float32)
+    steps = []
+    for t in range(3):
+        order = rng.permutation(V)
+        nbr = [rng.integers(0, n[k], (n[k + 1], f)).astype(np.int32)
+               for k, f in enumerate((2, 3))]
+        nbr[0][0] = -1                                    # no neighbour
+        steps.append([ref.RankBatch(
+            vids=[order[:k] for k in n], solid=[np.ones(k, bool) for k in n],
+            nbr_idx=nbr, labels=rng.integers(0, 3, 4).astype(np.int32),
+            seed_mask=np.array([True, True, True, False]), seed=t)])
+    with jax.default_matmul_precision("highest"):
+        res = ref.run_reference(m, features, steps, [None], 7, nc=2,
+                                delay=1, life_span=2)
+
+    def mean_nll(params, b):
+        h = jnp.asarray(features[b.vids[0]])
+        for k, p in enumerate(params["layers"]):
+            nbr = b.nbr_idx[k]
+            summed = jnp.where((nbr >= 0)[..., None], h[nbr], 0).sum(1)
+            h = (summed @ p["wn"] + 1.5 * (h[:len(nbr)] @ p["ws"])
+                 + p["b"])
+            if k == 0:
+                h = jnp.tanh(h)
+        logp = jax.nn.log_softmax(h, -1)
+        nll = -logp[jnp.arange(4), b.labels]
+        return (nll * b.seed_mask).sum() / b.seed_mask.sum()
+
+    params = res.params0
+    mu = jax.tree_util.tree_map(jnp.zeros_like, params)
+    nu = mu
+    for t, (b,) in enumerate(steps, start=1):
+        with jax.default_matmul_precision("highest"):
+            loss, g = jax.value_and_grad(mean_nll)(params, b)
+        norm = jnp.sqrt(sum((x * x).sum()
+                            for x in jax.tree_util.tree_leaves(g)))
+        g = jax.tree_util.tree_map(lambda x: x * min(1.0, 1 / (norm + 1e-9)),
+                                   g)
+        assert res.losses[t - 1] == pytest.approx(float(loss), rel=1e-5)
+        for a, r in zip(jax.tree_util.tree_leaves(g),
+                        jax.tree_util.tree_leaves(res.grads[t - 1])):
+            np.testing.assert_allclose(r, a, rtol=1e-4, atol=1e-6)
+        mu = jax.tree_util.tree_map(lambda a, x: 0.9 * a + 0.1 * x, mu, g)
+        nu = jax.tree_util.tree_map(lambda a, x: 0.999 * a + 0.001 * x * x,
+                                    nu, g)
+        params = jax.tree_util.tree_map(
+            lambda p, a, v: p - 0.05 * (a / (1 - 0.9 ** t))
+            / (jnp.sqrt(v / (1 - 0.999 ** t)) + 1e-8), params, mu, nu)
+    for a, r in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(res.params)):
+        np.testing.assert_allclose(r, a, rtol=1e-4, atol=1e-6)
+
+
+def test_calibrate_prints_each_reading_as_it_exists(monkeypatch, capsys):
+    """A stand-in that never returns, as in a call cut by its time limit,
+    leaves the seed's program line and each earlier stand-in's line
+    printed."""
+    import calibrate
+    from core import check, session
+    from core import device as dev
+
+    class Cut(Exception):
+        pass
+
+    class Readings:
+        def __init__(self, *args):
+            self.res = types.SimpleNamespace(losses=[2.0])
+
+        def program(self):
+            return {"loss_gap": 1e-4}
+
+        def control(self):
+            return {"grad_err": 0.05}
+
+        def half_batch(self):
+            raise Cut
+
+    run = types.SimpleNamespace(setup_s=1.0, memory_peak_bytes=7)
+    monkeypatch.setattr(dev, "require_chips", lambda chips: {})
+    monkeypatch.setattr(dev, "set_compile_cache", lambda path: None)
+    monkeypatch.setattr(dev, "CompileClock", lambda: None)
+    for name in ("load_graph", "load_partition", "build_config"):
+        monkeypatch.setattr(session, name, lambda *a: None)
+    monkeypatch.setattr(session, "run_cell", lambda *a: (
+        run, types.SimpleNamespace(losses=[2.0])))
+    monkeypatch.setattr(check, "Check", Readings)
+    with pytest.raises(Cut):
+        calibrate.main(["--workload", CELLS[0], "--seeds", "5,6",
+                        "--stand-ins", "1"])
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert [x["seed"] for x in lines] == [5, 5]
+    assert lines[0]["program"] == {"loss_gap": 1e-4}
+    assert lines[1]["stand_in"] == "control"
+    assert lines[1]["numbers"] == {"grad_err": 0.05}
+
+
 def _record(trace=True):
     from core.session import Run
     run = Run(setup_s=20.5, compile_s=0.3, window_s=31.0, epochs=8,
